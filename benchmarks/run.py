@@ -7,9 +7,6 @@
                                    + planned-collective sections: tuned vs
                                    fixed axis splits and the 3D planner
                                    cache-hit proof
-  trainer_step                  -- trainer-step offload-vs-raw comparison on
-                                   a 2x2 CPU mesh (subprocess): per-step
-                                   wall-clock + bitwise/cache-hit assertions
   service_throughput            -- multi-tenant broker requests/sec and
                                    p50/p99 latency vs client count, with
                                    coalescing on/off
@@ -26,8 +23,7 @@ Prints ``name,...,derived`` CSV sections. Run:
 
 ``--smoke`` runs only the offload-engine smoke (budgeted tuning grid +
 descriptor-cache proof + one 3D planned collective end-to-end with an
-asserted schedule-cache hit rate + a 2-step offloaded trainer on a 2x2 mesh
-asserted bitwise against the raw shard_map baseline + the service broker's
+asserted schedule-cache hit rate + the service broker's
 coalesce/bitwise proof + the plan-optimizer's fused-vs-unfused rounds/
 bitwise/device-latency proof) — the CI regression gate for the offload
 subsystem.
@@ -50,7 +46,6 @@ from benchmarks import (  # noqa: E402
     report,
     scan_latency,
     service_throughput,
-    trainer_step,
     tuned_vs_static,
 )
 
@@ -95,13 +90,6 @@ def main() -> None:
             "cache proof ==="
         )
         for row in tuned_vs_static.smoke():
-            print(row)
-        print()
-        print(
-            "# === Offloaded trainer smoke: 2-step DP trainer on a 2x2 "
-            "mesh, engine vs raw (bitwise) ==="
-        )
-        for row in trainer_step.smoke():
             print(row)
         print()
         print(
@@ -178,14 +166,6 @@ def main() -> None:
     for row in tuned_vs_static.planned_smoke():
         print(row)
 
-    print()
-    print("# === Trainer step: offload-engine vs raw collectives ===")
-    print("trainer_step,mode,ms_per_step")
-    try:
-        for row in trainer_step.run(bench_iters=3 if args.quick else 5):
-            print(row)
-    except Exception as e:  # subprocess needs a CPU with >= 4 threads
-        print(f"(trainer-step comparison unavailable: {e})")
 
     print()
     print("# === Service throughput: multi-tenant broker, coalesce on/off ===")

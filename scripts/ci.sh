@@ -75,10 +75,13 @@ cp benchmarks/BENCH_service.json "$BASE_DIR/BENCH_service.json"
 cp benchmarks/BENCH_obs.json "$BASE_DIR/BENCH_obs.json"
 cp benchmarks/BENCH_reliability.json "$BASE_DIR/BENCH_reliability.json"
 python -m benchmarks.run --smoke --report-json | tee "$SMOKE_OUT"
+# the offloaded trainer gate runs in a process of its own: it fixes the host
+# device count before importing jax
+TRAIN_OUT="$(python -m repro.testing.train_offload_check 2 2 --steps 2)"
+grep -q "^trainer_offload_summary,bitwise_equal,1,step2_cache_hit,1," <<<"$TRAIN_OUT" \
+  || { echo "CI FAIL: offloaded trainer smoke missing or not bitwise"; exit 1; }
 grep -q "^planned_smoke_summary," "$SMOKE_OUT" \
   || { echo "CI FAIL: planned 3D smoke section missing"; exit 1; }
-grep -q "^trainer_offload_summary,bitwise_equal,1,step2_cache_hit,1," "$SMOKE_OUT" \
-  || { echo "CI FAIL: offloaded trainer smoke missing or not bitwise"; exit 1; }
 grep -q "^service_smoke_summary,bitwise_equal,1,coalesce_gt1,1," "$SMOKE_OUT" \
   || { echo "CI FAIL: service smoke missing, not bitwise, or not coalescing"; exit 1; }
 grep -q "^fusion_summary,bitwise_equal,1,rounds_reduced,1,device_latency,1," "$SMOKE_OUT" \

@@ -110,9 +110,7 @@ class SpmdBackend(Backend):
     def __init__(self, axis_name: str, axis_size: int | None = None):
         self.axis_name = axis_name
         if axis_size is None:
-            from repro.compat import axis_size as _axis_size
-
-            axis_size = _axis_size(axis_name)
+            axis_size = lax.axis_size(axis_name)
         self.p = int(axis_size)
 
     def rank(self):

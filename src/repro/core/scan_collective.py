@@ -31,12 +31,6 @@ from repro.core.selector import select_algorithm
 PyTree = Any
 
 
-def _axis_size(axis_name: str) -> int:
-    from repro.compat import axis_size
-
-    return axis_size(axis_name)
-
-
 def dist_scan(
     x: PyTree,
     op: "AssocOp | str",
@@ -58,7 +52,7 @@ def dist_scan(
         wins (the software layer pre-assigned roles, as in the paper).
     """
     op = get_operator(op)
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     if descriptor is not None:
         algorithm = descriptor.algo_type
     if algorithm == "auto":
@@ -82,7 +76,7 @@ def dist_exscan(
     identity is strictly more useful and is what our SSM/MoE layers need).
     """
     op = get_operator(op)
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     if descriptor is not None:
         algorithm = descriptor.algo_type
     if algorithm == "auto":

@@ -1,13 +1,16 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-Dispatch policy: on TPU the Pallas kernels run natively; elsewhere (this CPU
-container) the default is the jnp reference (identical semantics & FLOPs) so
+Dispatch policy: on TPU the Pallas kernels run natively; elsewhere (the CPU
+backend) the default is the jnp reference (identical semantics & FLOPs) so
 that full-model compiles stay tractable, and ``force_pallas=True`` (or env
 REPRO_FORCE_PALLAS=1) routes through the kernels in interpret mode — that is
 how the kernel test-suite executes them.
 
 The wrappers own the ugly parts: shape flattening, padding to tile multiples,
-and exclusive-shift handling, so kernels stay minimal.
+and exclusive-shift handling, so kernels stay minimal. The additive prefix
+scan carries a custom VJP whose backward pass runs the same kernel on
+time-reversed input, so ``jax.grad`` of a model step goes through the kernel
+on the chip too.
 """
 
 from __future__ import annotations
@@ -34,6 +37,27 @@ def _use_pallas(force_pallas: bool | None) -> tuple[bool, bool]:
     if on_tpu:
         return True, False
     return (True, True) if force_pallas else (False, True)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _cumsum_2d(x, block_rows, block_len, interpret):
+    return prefix_scan_pallas(
+        x, op="add", block_rows=block_rows, block_len=block_len,
+        interpret=interpret,
+    )
+
+
+def _cumsum_2d_fwd(x, block_rows, block_len, interpret):
+    return _cumsum_2d(x, block_rows, block_len, interpret), None
+
+
+def _cumsum_2d_bwd(block_rows, block_len, interpret, _, g):
+    # d/dx_j of sum_{i>=j} g_i: a reverse cumulative sum
+    rev = _cumsum_2d(g[:, ::-1], block_rows, block_len, interpret)
+    return (rev[:, ::-1],)
+
+
+_cumsum_2d.defvjp(_cumsum_2d_fwd, _cumsum_2d_bwd)
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int, fill) -> tuple[jax.Array, int]:
@@ -82,9 +106,12 @@ def prefix_scan(
         br //= 2
     while flat.shape[1] % bl:
         bl //= 2
-    out = prefix_scan_pallas(
-        flat, op=op, block_rows=br, block_len=bl, interpret=interpret
-    )
+    if op == "add":
+        out = _cumsum_2d(flat, br, bl, interpret)
+    else:
+        out = prefix_scan_pallas(
+            flat, op=op, block_rows=br, block_len=bl, interpret=interpret
+        )
     out = out[:rows, :length].reshape(shape)
     if exclusive:
         ident = fill

@@ -12,30 +12,41 @@ Quadrics barrier and sPIN handler papers in PAPERS.md).
 Two forms share the same round structure:
 
 * **spmd form** (``axis_names`` given): a per-rank kernel run under
-  ``shard_map`` over ONE named mesh axis. Round ``k`` posts a remote copy of
-  the accumulator into the partner's double-buffered receive slot
-  (``recv[k % 2]``), blocks on that slot's DMA semaphores, masks the cyclic
-  wrap back to ``ppermute``'s zero-fill, and folds with the exact operand
-  order of the op-per-round schedule — outputs are bitwise identical to
-  ``lower_spmd``.
+  ``shard_map`` over ONE named mesh axis. Each exchange posts a remote copy
+  of the accumulator into a receive slot of its own on the partner (a slot
+  is written once per kernel, so a fast sender never overwrites data its
+  receiver has not read), blocks on that slot's DMA semaphores, masks the
+  cyclic wrap back to ``ppermute``'s zero-fill, and folds with the exact
+  operand order of the op-per-round schedule — outputs are bitwise
+  identical to ``lower_spmd``.
 * **sim form** (no ``axis_names``): the single-device rehearsal over stacked
   ``(p, ...)`` leaves; the same rounds run as local ``make_async_copy``
-  row-block shifts. This is the variant the autotuner races against the
-  op-per-round interpreter and the engine's sim mode dispatches.
+  shifts of rank slabs through two alternating receive slots. This is the
+  variant the autotuner races against the op-per-round interpreter and the
+  engine's sim mode dispatches.
+
+Payloads stay in HBM; each kernel copies them into VMEM tiles laid out for
+Mosaic (see "Tile layout" below) and copies the results back out. The whole
+payload is resident in VMEM, so :func:`supports_plan` refuses any plan whose
+tiles exceed the scoped VMEM the kernels ask for.
 
 Where no TPU is attached the kernels run in Pallas interpret mode, which
 fully discharges the DMAs — CI exercises the real send/wait structure on
-CPU. The interpreter's remote-DMA discharge requires a *scalar* logical
-``device_id``, exactly one named mesh axis in scope, and rounds that are
-full permutations; the kernels honor all three (cyclic sends + receiver
-masking reproduce the partial-permutation zero-fill), and plans outside the
-supported set (multi-axis under shard_map, chunked C > 1, non-doubling scan
-algorithms, non-pow2 butterflies) are reported by :func:`supports_plan` so
-the lowering registry can fall back to the op-per-round default.
+CPU. On a TPU they never do. The interpreter's remote-DMA discharge requires
+a *scalar* logical ``device_id``, exactly one named mesh axis in scope, and
+rounds that are full permutations; the kernels honor all three (cyclic
+sends + receiver masking reproduce the partial-permutation zero-fill), and
+plans outside the supported set (multi-axis under shard_map, chunked C > 1,
+non-doubling scan algorithms, non-pow2 butterflies, operators that are not
+leafwise, payloads over the VMEM budget, and the spmd form on a TPU, whose
+remote DMAs have run only in interpret mode) are reported by
+:func:`supports_plan` so the lowering registry can fall back to the
+op-per-round default.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
@@ -68,14 +79,9 @@ _COMM_KINDS = (
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return False
-
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    return (not on_tpu()) if interpret is None else bool(interpret)
+    """Is the default device a TPU? Device errors propagate: a failed query
+    must not quietly select interpret mode."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def active_level(plan: CollectivePlan) -> Optional[int]:
@@ -94,9 +100,17 @@ def supports_plan(
     Supported: effectively single-axis plans (one logical level of size
     > 1; size-1 levels run the identical local shortcuts), whole payloads
     (chunking == 1), hillis-steele SCAN / FUSED_SCAN_TOTAL over a
-    zero-identity operator, and pow2 TOTAL/BARRIER butterflies. The spmd
-    form additionally requires exactly one named mesh axis (the interpret
-    remote-DMA discharge supports no more).
+    zero-identity operator, and pow2 TOTAL/BARRIER butterflies, over a
+    leafwise operator (the tile layout flattens leaves), with tiles that fit
+    the kernels' scoped VMEM (:func:`kernel_vmem_bytes` against
+    :func:`vmem_limit_bytes`; reason ``vmem``) — a plan that does not say
+    its payload size (``payload_bytes == 0``) is refused as
+    ``payload_unknown``. The spmd form additionally requires exactly one
+    named mesh axis (the interpret remote-DMA discharge supports no more),
+    and is refused on a TPU (``spmd_unverified``): it compiles for the
+    chip, but its remote DMAs have run only in interpret mode, and no
+    engine entry point reaches it — planned descriptors span two or more
+    mesh axes, refused as ``multi_axis_mesh``.
     """
     if plan.chunking > 1:
         return False, "chunked"
@@ -104,6 +118,10 @@ def supports_plan(
         len(axis_names) != 1 or len(plan.sizes) != 1
     ):
         return False, "multi_axis_mesh"
+    if axis_names is not None and on_tpu():
+        return False, "spmd_unverified"
+    if plan.payload_bytes <= 0:
+        return False, "payload_unknown"
     lv = active_level(plan)
     if lv is None:
         return False, "not_single_axis"
@@ -127,6 +145,10 @@ def supports_plan(
                 return False, "non_pow2_butterfly"
         else:
             return False, f"phase:{ph.kind.name.lower()}"
+        if ph.kind != PhaseKind.BARRIER and op.name not in _LEAFWISE_OPS:
+            return False, "op_layout"
+    if kernel_vmem_bytes(plan, axis_names) > vmem_limit_bytes():
+        return False, "vmem"
     return True, ""
 
 
@@ -155,6 +177,164 @@ def kernel_round_structure(
 
 
 # ---------------------------------------------------------------------------
+# Tile layout shared by both forms
+# ---------------------------------------------------------------------------
+#
+# Mosaic DMAs and loads only whole (sublane, lane) tiles. So each rank's
+# share of a leaf is flattened and zero-padded to a ``(rows, 128)`` tile,
+# ``rows`` a multiple of the dtype's sublane count; the sim form stacks
+# ranks on a leading, untiled axis ``(p, rows, 128)``, where rank slices
+# are free. Combines run over blocks of ``_BLOCK_ROWS`` rows so the values
+# the kernel holds stay a few vregs, whatever the payload.
+
+_LANES = 128
+_BLOCK_ROWS = 64
+#: operators that combine each leaf elementwise: the only ones whose result
+#: survives flattening and padding the leaves into tiles
+_LEAFWISE_OPS = frozenset({"sum", "prod", "max", "min"})
+
+
+def _sublanes(dtype) -> int:
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _tile_rows(n: int, dtype) -> int:
+    """Rows of the ``(rows, 128)`` tile holding ``n`` elements: sublane
+    aligned, and a whole number of combine blocks when it spans several."""
+    rows = -(-max(int(n), 1) // _LANES)
+    step = _BLOCK_ROWS if rows > _BLOCK_ROWS else _sublanes(dtype)
+    return -(-rows // step) * step
+
+
+def _pack(leaf: jax.Array, stacked: bool) -> jax.Array:
+    """``(...) -> (rows, 128)``, or ``(p, ...) -> (p, rows, 128)``."""
+    lead = leaf.shape[:1] if stacked else ()
+    flat = leaf.reshape(lead + (-1,))
+    n = flat.shape[-1]
+    rows = _tile_rows(n, leaf.dtype)
+    pad = [(0, 0)] * len(lead) + [(0, rows * _LANES - n)]
+    return jnp.pad(flat, pad).reshape(lead + (rows, _LANES))
+
+
+def _unpack(tile: jax.Array, like_shape, stacked: bool) -> jax.Array:
+    lead = like_shape[:1] if stacked else ()
+    n = math.prod(like_shape[len(lead):])
+    return tile.reshape(lead + (-1,))[..., :n].reshape(like_shape)
+
+
+def _for_blocks(rows: int, fn) -> None:
+    """Call ``fn(block)`` for every ``pl.ds`` row block of a tile."""
+    br = min(rows, _BLOCK_ROWS)
+    if rows == br:
+        fn(pl.ds(0, br))
+        return
+
+    def body(i, carry):
+        fn(pl.ds(pl.multiple_of(i * br, br), br))
+        return carry
+
+    lax.fori_loop(0, rows // br, body, 0)
+
+
+def vmem_limit_bytes() -> int:
+    """Scoped VMEM the fused kernels ask for: 3/4 of one TensorCore's VMEM
+    on the attached TPU. Off the chip the kernels run in interpret mode and
+    are sized for the TPU v5e (128 MiB), so a plan is admitted or refused
+    alike on both."""
+    capacity = 128 * 2**20
+    if jax.devices()[0].platform == "tpu":
+        capacity = pltpu.get_tpu_info().vmem_capacity_bytes
+    return capacity * 3 // 4
+
+
+def _recv_slots(p: int) -> int:
+    """Receive slots per stream in the spmd form: one per exchange (a
+    stream makes at most ``num_steps(p) + 1``), so no send can overwrite a
+    slot its receiver has not read yet."""
+    return alg.num_steps(p) + 1
+
+
+def _vmem_bytes(tile_bytes: int, streams: int, slots: int) -> int:
+    """VMEM a comm kernel holds for ``tile_bytes`` of packed tiles (all
+    leaves, all stacked ranks): per stream an accumulator plus ``slots``
+    receive slots. Payload in and out stay in HBM."""
+    return streams * (1 + slots) * tile_bytes
+
+
+def kernel_vmem_bytes(
+    plan: CollectivePlan, axis_names: Optional[Sequence[str]] = None
+) -> int:
+    """VMEM one fused comm kernel of ``plan`` holds when its per-rank
+    payload (``plan.payload_bytes``, priced as 32-bit words) is one leaf:
+    receive slots are two in the sim form, :func:`_recv_slots` in the spmd
+    form, and the sim form stacks ``p`` ranks. A payload split over several
+    leaves pads each leaf to its own tile and can need more; the kernel
+    checks the tiles it actually builds (:func:`_launch`)."""
+    lv = active_level(plan)
+    p = plan.logical_sizes[lv] if lv is not None else 1
+    tile = _tile_rows(-(-plan.payload_bytes // 4), jnp.float32) * _LANES * 4
+    streams = (
+        2
+        if any(ph.kind == PhaseKind.FUSED_SCAN_TOTAL for ph in plan.phases)
+        else 1
+    )
+    if axis_names is None:
+        return _vmem_bytes(plan.p * tile, streams, 2)
+    return _vmem_bytes(tile, streams, _recv_slots(p))
+
+
+def _launch(
+    body, leaves, treedef, tiles, streams, slots, semaphores, *,
+    stacked, interpret,
+):
+    """Run a comm-phase kernel over packed tiles: payloads in and results
+    out stay in HBM (``pl.ANY``); VMEM holds one accumulator and ``slots``
+    receive slots per leaf and stream. Returns the result tree, or the
+    ``(scan, total)`` pair of trees for two streams. Raises ``ValueError``
+    when those tiles exceed :func:`vmem_limit_bytes` (a payload of many
+    small leaves can, although its plan was admitted)."""
+    need = _vmem_bytes(
+        sum(t.size * t.dtype.itemsize for t in tiles), streams, slots
+    )
+    if need > vmem_limit_bytes():
+        raise ValueError(
+            f"fused collective kernel needs {need} bytes of VMEM for "
+            f"{len(tiles)} packed leaves, over its {vmem_limit_bytes()}-byte "
+            "limit; use the registry default lowering"
+        )
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    outs = pl.pallas_call(
+        body,
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tiles]
+        * streams,
+        in_specs=[any_spec] * len(tiles),
+        out_specs=[any_spec] * (len(tiles) * streams),
+        scratch_shapes=(
+            [pltpu.VMEM(t.shape, t.dtype) for t in tiles] * streams
+            + [pltpu.VMEM((slots,) + t.shape, t.dtype) for t in tiles]
+            * streams
+            + semaphores
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes()
+        ),
+        interpret=interpret,
+    )(*tiles)
+    L = len(leaves)
+    trees = [
+        jax.tree.unflatten(
+            treedef,
+            [
+                _unpack(o, leaf.shape, stacked)
+                for o, leaf in zip(outs[i * L : (i + 1) * L], leaves)
+            ],
+        )
+        for i in range(streams)
+    ]
+    return trees[0] if streams == 1 else tuple(trees)
+
+
+# ---------------------------------------------------------------------------
 # spmd form: per-rank kernels under shard_map, remote DMA rounds
 # ---------------------------------------------------------------------------
 #
@@ -163,18 +343,6 @@ def kernel_round_structure(
 # the invariant the interpret discharge rule needs — and the receiver masks
 # wrapped rows back to zero, reproducing the op-per-round ``ppermute``
 # zero-fill bit for bit.
-
-
-def _start_rounds(copies):
-    """Issue all of a round's DMAs before waiting on any (full duplex)."""
-    for c in copies:
-        c.start()
-    for c in copies:
-        c.wait()
-
-
-def _masked(tree_leaves, mask):
-    return [jnp.where(mask, v, jnp.zeros_like(v)) for v in tree_leaves]
 
 
 def _spmd_comm_kernel(
@@ -188,175 +356,167 @@ def _spmd_comm_kernel(
 ):
     """Build ``fn(tree) -> tree`` (or ``(tree, tree)`` for FUSED_SCAN_TOTAL)
     running one whole comm phase as a single per-rank Pallas kernel."""
+    nsteps = alg.num_steps(p)
+    fused = kind == PhaseKind.FUSED_SCAN_TOTAL
+    streams = 2 if fused else 1
+    nslots = _recv_slots(p)
 
     def run(x: PyTree):
         leaves, treedef = jax.tree.flatten(x)
-        # scalar leaves (the barrier token) ride as (1, 1) blocks
-        shapes = [l.shape for l in leaves]
-        leaves = [l.reshape((1, 1)) if l.ndim == 0 else l for l in leaves]
-        L = len(leaves)
-        nsteps = alg.num_steps(p)
-        fused = kind == PhaseKind.FUSED_SCAN_TOTAL
-        streams = 2 if fused else 1
-
-        def combine(lhs_leaves, rhs_leaves):
-            merged = op.combine(
-                jax.tree.unflatten(treedef, lhs_leaves),
-                jax.tree.unflatten(treedef, rhs_leaves),
-            )
-            return jax.tree.flatten(merged)[0]
+        tiles = [_pack(l, stacked=False) for l in leaves]
+        L = len(tiles)
+        ns = L * streams
 
         def body(*refs):
-            ins = refs[:L]
-            outs = refs[L : L * (1 + streams)]
-            scratch = refs[L * (1 + streams):]
-            acc = scratch[: L * streams]
-            recv = scratch[L * streams : 2 * L * streams]
-            send_sem, recv_sem = scratch[2 * L * streams :]
+            ins, outs = refs[:L], refs[L : L + ns]
+            acc = refs[L + ns : L + 2 * ns]
+            recv = refs[L + 2 * ns : L + 3 * ns]
+            copy_sem, send_sem, recv_sem = refs[L + 3 * ns :]
             rank = lax.axis_index(axis_name)
-            step = 0
+            steps = [0] * streams
+
+            def local_copies(pairs):
+                copies = [
+                    pltpu.make_async_copy(src, dst, copy_sem.at[i])
+                    for i, (src, dst) in enumerate(pairs)
+                ]
+                for c in copies:
+                    c.start()
+                for c in copies:
+                    c.wait()
 
             def exchange(dst_rank, stream):
-                """One full-permutation round: remote-copy every leaf of one
-                stream's accumulator into the partner's recv slot. Returns
-                ``(copies, read)``; ``read()`` loads the received leaves and
-                must only run after the copies' ``wait`` (via
-                ``_start_rounds``)."""
-                nonlocal step
-                slot = step & 1
-                step += 1
-                copies = []
-                for li in range(L):
-                    si = stream * L + li
-                    copies.append(
-                        pltpu.make_async_remote_copy(
-                            src_ref=acc[si],
-                            dst_ref=recv[si].at[slot],
-                            send_sem=send_sem.at[slot, si],
-                            recv_sem=recv_sem.at[slot, si],
-                            device_id=dst_rank,
-                            device_id_type=pltpu.DeviceIdType.LOGICAL,
-                        )
+                """Remote-copy one stream's accumulators into the partner's
+                next receive slot; returns ``(copies, slot)``. Read the slot
+                only after the copies' ``wait``."""
+                slot = steps[stream]
+                steps[stream] += 1
+                copies = [
+                    pltpu.make_async_remote_copy(
+                        src_ref=acc[si],
+                        dst_ref=recv[si].at[slot],
+                        send_sem=send_sem.at[slot, si],
+                        recv_sem=recv_sem.at[slot, si],
+                        device_id=dst_rank,
+                        device_id_type=pltpu.DeviceIdType.LOGICAL,
                     )
-                read = lambda: [  # noqa: E731
-                    recv[stream * L + li][slot] for li in range(L)
+                    for si in range(stream * L, (stream + 1) * L)
                 ]
-                return copies, read
+                return copies, slot
 
-            def set_acc(stream, vals):
+            def run_round(*copies):
+                """Issue all of a round's DMAs before waiting on any (full
+                duplex)."""
+                for c in copies:
+                    c.start()
+                for c in copies:
+                    c.wait()
+
+            def fold(update):
+                """``update(li, blk)`` over every leaf's row blocks."""
                 for li in range(L):
-                    acc[stream * L + li][...] = vals[li]
+                    _for_blocks(
+                        tiles[li].shape[0], lambda blk, li=li: update(li, blk)
+                    )
 
-            def get_acc(stream):
-                return [acc[stream * L + li][...] for li in range(L)]
+            def masked(v, keep):
+                return jnp.where(keep, v, jnp.zeros_like(v))
 
+            local_copies([(ins[li], acc[li]) for li in range(L)])
             if kind in (PhaseKind.TOTAL, PhaseKind.BARRIER):
                 # pow2 recursive-doubling butterfly; the XOR rounds are full
                 # permutations so the flag stream of allreduce_schedule is
                 # constantly 1 and _combine_lr reduces to a plain combine.
-                set_acc(0, [r[...] for r in ins])
                 for k in range(nsteps):
                     d = 1 << k
-                    copies, read = exchange(rank ^ d, 0)
-                    _start_rounds(copies)
-                    rv = read()
+                    copies, slot = exchange(rank ^ d, 0)
+                    run_round(*copies)
                     partner_lower = (rank & d) != 0
-                    lo = combine(rv, get_acc(0))
-                    hi = combine(get_acc(0), rv)
-                    set_acc(
-                        0,
-                        [
-                            jnp.where(partner_lower, l, h)
-                            for l, h in zip(lo, hi)
-                        ],
-                    )
-                for li in range(L):
-                    outs[li][...] = acc[li][...]
+
+                    def butterfly(li, blk, slot=slot, lower=partner_lower):
+                        rv = recv[li][slot, blk, :]
+                        mine = acc[li][blk, :]
+                        acc[li][blk, :] = jnp.where(
+                            lower, op.combine(rv, mine), op.combine(mine, rv)
+                        )
+
+                    fold(butterfly)
+                local_copies([(acc[li], outs[li]) for li in range(L)])
                 return
 
             # doubling scans: stream 0 = prefix, stream 1 = suffix (fused)
-            set_acc(0, [r[...] for r in ins])
             if not inclusive:
                 # structural entry shift: rank r starts from x_{r-1}
-                copies, read = exchange(lax.rem(rank + 1, p), 0)
-                _start_rounds(copies)
-                set_acc(0, _masked(read(), rank >= 1))
+                copies, slot = exchange(lax.rem(rank + 1, p), 0)
+                run_round(*copies)
+
+                def entry(li, blk, slot=slot):
+                    acc[li][blk, :] = masked(recv[li][slot, blk, :], rank >= 1)
+
+                fold(entry)
             if fused:
-                set_acc(1, [r[...] for r in ins])
+                local_copies([(ins[li], acc[L + li]) for li in range(L)])
             for k in range(nsteps):
                 d = 1 << k
-                pre_copies, pre_read = exchange(lax.rem(rank + d, p), 0)
+                pre, pre_slot = exchange(lax.rem(rank + d, p), 0)
+                suf, suf_slot = [], None
                 if fused:
-                    suf_copies, suf_read = exchange(
-                        lax.rem(rank - d + p, p), 1
-                    )
-                    # full duplex: both directions' sends in flight at once
-                    _start_rounds(pre_copies + suf_copies)
-                else:
-                    _start_rounds(pre_copies)
-                pre = combine(_masked(pre_read(), rank >= d), get_acc(0))
-                if fused:
-                    set_acc(
-                        1,
-                        combine(
-                            get_acc(1), _masked(suf_read(), rank < p - d)
-                        ),
-                    )
-                set_acc(0, pre)
-            if not fused:
-                for li in range(L):
-                    outs[li][...] = acc[li][...]
-                return
-            # fused exits (same arithmetic as alg.scan_total_schedule)
-            if inclusive:
-                copies, read = exchange(lax.rem(rank - 1 + p, p), 1)
-                _start_rounds(copies)
-                total = combine(get_acc(0), _masked(read(), rank < p - 1))
-                y = get_acc(0)
-            else:
-                total = combine(get_acc(0), get_acc(1))
-                y = _masked(get_acc(0), rank != 0)
-            for li in range(L):
-                outs[li][...] = y[li]
-                outs[L + li][...] = total[li]
+                    suf, suf_slot = exchange(lax.rem(rank - d + p, p), 1)
+                # full duplex: both directions' sends in flight at once
+                run_round(*pre, *suf)
 
-        out_shape = [
-            jax.ShapeDtypeStruct(l.shape, l.dtype) for l in leaves
-        ] * streams
-        scratch_shapes = (
-            [pltpu.VMEM(l.shape, l.dtype) for l in leaves] * streams
-            + [pltpu.VMEM((2,) + l.shape, l.dtype) for l in leaves] * streams
-            + [
-                pltpu.SemaphoreType.DMA((2, L * streams)),
-                pltpu.SemaphoreType.DMA((2, L * streams)),
-            ]
-        )
-        outs = pl.pallas_call(
-            body,
-            out_shape=out_shape,
-            scratch_shapes=scratch_shapes,
-            interpret=interpret,
-        )(*leaves)
-        if streams == 1:
-            outs = [o.reshape(s) for o, s in zip(outs, shapes)]
-            return jax.tree.unflatten(treedef, outs)
-        y = [o.reshape(s) for o, s in zip(outs[:L], shapes)]
-        t = [o.reshape(s) for o, s in zip(outs[L:], shapes)]
-        return (
-            jax.tree.unflatten(treedef, y),
-            jax.tree.unflatten(treedef, t),
+                def scan_round(li, blk, d=d, pre_slot=pre_slot,
+                               suf_slot=suf_slot):
+                    acc[li][blk, :] = op.combine(
+                        masked(recv[li][pre_slot, blk, :], rank >= d),
+                        acc[li][blk, :],
+                    )
+                    if fused:
+                        si = L + li
+                        acc[si][blk, :] = op.combine(
+                            acc[si][blk, :],
+                            masked(recv[si][suf_slot, blk, :], rank < p - d),
+                        )
+
+                fold(scan_round)
+            if fused:
+                # exits (same arithmetic as alg.scan_total_schedule); the
+                # total lands in the suffix accumulator
+                if inclusive:
+                    copies, slot = exchange(lax.rem(rank - 1 + p, p), 1)
+                    run_round(*copies)
+
+                    def exit_(li, blk, slot=slot):
+                        acc[L + li][blk, :] = op.combine(
+                            acc[li][blk, :],
+                            masked(recv[L + li][slot, blk, :], rank < p - 1),
+                        )
+                else:
+
+                    def exit_(li, blk):
+                        y = acc[li][blk, :]
+                        acc[L + li][blk, :] = op.combine(y, acc[L + li][blk, :])
+                        acc[li][blk, :] = masked(y, rank != 0)
+
+                fold(exit_)
+            local_copies([(acc[si], outs[si]) for si in range(ns)])
+
+        return _launch(
+            body, leaves, treedef, tiles, streams, nslots,
+            [
+                pltpu.SemaphoreType.DMA((ns,)),
+                pltpu.SemaphoreType.DMA((nslots, ns)),
+                pltpu.SemaphoreType.DMA((nslots, ns)),
+            ],
+            stacked=False, interpret=interpret,
         )
 
     return run
 
 
 # ---------------------------------------------------------------------------
-# sim form: whole-mesh (p, ...) kernels, local DMA row-block shifts
+# sim form: whole-mesh (p, ...) kernels, local DMA rank-slab shifts
 # ---------------------------------------------------------------------------
-
-
-def _row_iota(shape) -> jnp.ndarray:
-    return lax.broadcasted_iota(jnp.int32, shape, 0)
 
 
 def _sim_comm_kernel(
@@ -369,175 +529,151 @@ def _sim_comm_kernel(
 ):
     """Build the single-device form over stacked ``(p, ...)`` leaves: the
     same rounds as the spmd kernel, realized as local ``make_async_copy``
-    row-block shifts (rank r's row moves to row r+d), with the uncovered
-    rows masked to zero exactly like ``ppermute``'s zero-fill."""
+    shifts of rank slabs (rank r's tile moves to rank r+d); ranks with no
+    sender combine zeros, exactly like ``ppermute``'s zero-fill. Ranks are
+    static here, so the masks are too."""
+    nsteps = alg.num_steps(p)
+    fused = kind == PhaseKind.FUSED_SCAN_TOTAL
+    streams = 2 if fused else 1
 
     def run(x: PyTree):
         leaves, treedef = jax.tree.flatten(x)
-        L = len(leaves)
-        nsteps = alg.num_steps(p)
-        fused = kind == PhaseKind.FUSED_SCAN_TOTAL
-        streams = 2 if fused else 1
-
-        def combine(lhs_leaves, rhs_leaves):
-            merged = op.combine(
-                jax.tree.unflatten(treedef, lhs_leaves),
-                jax.tree.unflatten(treedef, rhs_leaves),
-            )
-            return jax.tree.flatten(merged)[0]
+        tiles = [_pack(l, stacked=True) for l in leaves]
+        L = len(tiles)
+        ns = L * streams
 
         def body(*refs):
-            ins = refs[:L]
-            outs = refs[L : L * (1 + streams)]
-            scratch = refs[L * (1 + streams):]
-            acc = scratch[: L * streams]
-            recv = scratch[L * streams : 2 * L * streams]
-            sem = scratch[-1]
+            ins, outs = refs[:L], refs[L : L + ns]
+            acc = refs[L + ns : L + 2 * ns]
+            recv = refs[L + 2 * ns : L + 3 * ns]
+            sem = refs[-1]
             step = 0
 
-            def shift(srcs, d, stream):
-                """One round: every leaf's rows move by ``d`` (+d = toward
-                higher ranks) into this round's recv slot; rows with no
-                sender are masked to zero by the caller via the row mask."""
-                nonlocal step
-                slot = step & 1
-                step += 1
-                for li in range(L):
-                    si = stream * L + li
-                    src = srcs[li] if srcs is not None else acc[si]
-                    if d > 0:
-                        copy = pltpu.make_async_copy(
-                            src.at[pl.ds(0, p - d)],
-                            recv[si].at[slot, pl.ds(d, p - d)],
-                            sem.at[slot, si],
-                        )
-                    else:
-                        copy = pltpu.make_async_copy(
-                            src.at[pl.ds(-d, p + d)],
-                            recv[si].at[slot, pl.ds(0, p + d)],
-                            sem.at[slot, si],
-                        )
-                    copy.start()
-                    copy.wait()
-                return [recv[stream * L + li][slot] for li in range(L)]
-
-            def bfly(d, stream):
-                """XOR-partner round as 2*(p / 2d) block swaps (full perm)."""
-                nonlocal step
-                slot = step & 1
-                step += 1
-                for li in range(L):
-                    si = stream * L + li
-                    for base in range(0, p, 2 * d):
-                        for (a, b) in ((base, base + d), (base + d, base)):
-                            copy = pltpu.make_async_copy(
-                                acc[si].at[pl.ds(a, d)],
-                                recv[si].at[slot, pl.ds(b, d)],
-                                sem.at[slot, si],
-                            )
-                            copy.start()
-                            copy.wait()
-                return [recv[stream * L + li][slot] for li in range(L)]
-
-            def mask_rows(vals, keep):
-                return [
-                    jnp.where(
-                        keep(_row_iota(v.shape)), v, jnp.zeros_like(v)
-                    )
-                    for v in vals
+            def copy_all(pairs):
+                copies = [
+                    pltpu.make_async_copy(src, dst, sem.at[i])
+                    for i, (src, dst) in enumerate(pairs)
                 ]
+                for c in copies:
+                    c.start()
+                for c in copies:
+                    c.wait()
 
-            def set_acc(stream, vals):
+            def shift(d, stream):
+                """One round: every rank's tile moves by ``d`` (+d = toward
+                higher ranks) into this round's recv slot; returns the slot.
+                Rows with no sender are left unwritten."""
+                nonlocal step
+                slot = step & 1
+                step += 1
+                lo, n = (0, p - d) if d > 0 else (-d, p + d)
+                copy_all(
+                    (acc[si].at[pl.ds(lo, n)],
+                     recv[si].at[slot, pl.ds(lo + d, n)])
+                    for si in range(stream * L, (stream + 1) * L)
+                )
+                return slot
+
+            def bfly(d):
+                """XOR-partner round as 2*(p / 2d) slab swaps (full perm)."""
+                nonlocal step
+                slot = step & 1
+                step += 1
+                copy_all(
+                    (acc[li].at[pl.ds(a, d)], recv[li].at[slot, pl.ds(b, d)])
+                    for li in range(L)
+                    for base in range(0, p, 2 * d)
+                    for a, b in ((base, base + d), (base + d, base))
+                )
+                return slot
+
+            def fold(update):
+                """``update(li, r, blk)`` over every leaf, rank and block."""
                 for li in range(L):
-                    acc[stream * L + li][...] = vals[li]
 
-            def get_acc(stream):
-                return [acc[stream * L + li][...] for li in range(L)]
+                    def blocks(blk, li=li):
+                        for r in range(p):
+                            update(li, r, blk)
 
+                    _for_blocks(tiles[li].shape[1], blocks)
+
+            def recv_or_zero(si, slot, r, blk, has_sender):
+                v = recv[si][slot, r, blk, :]
+                return v if has_sender else jnp.zeros_like(v)
+
+            copy_all((ins[li], acc[li]) for li in range(L))
             if kind in (PhaseKind.TOTAL, PhaseKind.BARRIER):
-                set_acc(0, [r[...] for r in ins])
                 for k in range(nsteps):
                     d = 1 << k
-                    rv = bfly(d, 0)
-                    partner_lower = (_row_iota((p,)) & d) != 0
-                    lo = combine(rv, get_acc(0))
-                    hi = combine(get_acc(0), rv)
-                    set_acc(
-                        0,
-                        [
-                            jnp.where(
-                                partner_lower.reshape(
-                                    (p,) + (1,) * (l.ndim - 1)
-                                ),
-                                l,
-                                h,
-                            )
-                            for l, h in zip(lo, hi)
-                        ],
-                    )
-                for li in range(L):
-                    outs[li][...] = acc[li][...]
+                    slot = bfly(d)
+
+                    def butterfly(li, r, blk, d=d, slot=slot):
+                        rv = recv[li][slot, r, blk, :]
+                        mine = acc[li][r, blk, :]
+                        acc[li][r, blk, :] = (
+                            op.combine(rv, mine)
+                            if r & d
+                            else op.combine(mine, rv)
+                        )
+
+                    fold(butterfly)
+                copy_all((acc[li], outs[li]) for li in range(L))
                 return
 
-            if inclusive:
-                set_acc(0, [r[...] for r in ins])
-            else:
-                rv = shift(ins, 1, 0)
-                set_acc(0, mask_rows(rv, lambda r: r >= 1))
+            if not inclusive:
+                slot = shift(1, 0)
+
+                def entry(li, r, blk, slot=slot):
+                    acc[li][r, blk, :] = recv_or_zero(li, slot, r, blk, r >= 1)
+
+                fold(entry)
             if fused:
-                set_acc(1, [r[...] for r in ins])
+                copy_all((ins[li], acc[L + li]) for li in range(L))
             for k in range(nsteps):
                 d = 1 << k
-                rv = shift(None, d, 0)
-                pre = combine(
-                    mask_rows(rv, lambda r, _d=d: r >= _d), get_acc(0)
-                )
-                if fused:
-                    sv = shift(None, -d, 1)
-                    set_acc(
-                        1,
-                        combine(
-                            get_acc(1),
-                            mask_rows(sv, lambda r, _d=d: r < p - _d),
-                        ),
-                    )
-                set_acc(0, pre)
-            if not fused:
-                for li in range(L):
-                    outs[li][...] = acc[li][...]
-                return
-            if inclusive:
-                sv = shift(None, -1, 1)
-                total = combine(
-                    get_acc(0), mask_rows(sv, lambda r: r < p - 1)
-                )
-                y = get_acc(0)
-            else:
-                total = combine(get_acc(0), get_acc(1))
-                y = mask_rows(get_acc(0), lambda r: r != 0)
-            for li in range(L):
-                outs[li][...] = y[li]
-                outs[L + li][...] = total[li]
+                pre_slot = shift(d, 0)
+                suf_slot = shift(-d, 1) if fused else None
 
-        out_shape = [
-            jax.ShapeDtypeStruct(l.shape, l.dtype) for l in leaves
-        ] * streams
-        scratch_shapes = (
-            [pltpu.VMEM(l.shape, l.dtype) for l in leaves] * streams
-            + [pltpu.VMEM((2,) + l.shape, l.dtype) for l in leaves] * streams
-            + [pltpu.SemaphoreType.DMA((2, L * streams))]
-        )
-        outs = pl.pallas_call(
-            body,
-            out_shape=out_shape,
-            scratch_shapes=scratch_shapes,
-            interpret=interpret,
-        )(*leaves)
-        if streams == 1:
-            return jax.tree.unflatten(treedef, list(outs))
-        return (
-            jax.tree.unflatten(treedef, list(outs[:L])),
-            jax.tree.unflatten(treedef, list(outs[L:])),
+                def scan_round(li, r, blk, d=d, pre_slot=pre_slot,
+                               suf_slot=suf_slot):
+                    acc[li][r, blk, :] = op.combine(
+                        recv_or_zero(li, pre_slot, r, blk, r >= d),
+                        acc[li][r, blk, :],
+                    )
+                    if fused:
+                        si = L + li
+                        acc[si][r, blk, :] = op.combine(
+                            acc[si][r, blk, :],
+                            recv_or_zero(si, suf_slot, r, blk, r < p - d),
+                        )
+
+                fold(scan_round)
+            if fused:
+                if inclusive:
+                    slot = shift(-1, 1)
+
+                    def exit_(li, r, blk, slot=slot):
+                        acc[L + li][r, blk, :] = op.combine(
+                            acc[li][r, blk, :],
+                            recv_or_zero(L + li, slot, r, blk, r < p - 1),
+                        )
+                else:
+
+                    def exit_(li, r, blk):
+                        y = acc[li][r, blk, :]
+                        acc[L + li][r, blk, :] = op.combine(
+                            y, acc[L + li][r, blk, :]
+                        )
+                        if r == 0:
+                            acc[li][r, blk, :] = jnp.zeros_like(y)
+
+                fold(exit_)
+            copy_all((acc[si], outs[si]) for si in range(ns))
+
+        return _launch(
+            body, leaves, treedef, tiles, streams, 2,
+            [pltpu.SemaphoreType.DMA((L * max(p, streams),))],
+            stacked=True, interpret=interpret,
         )
 
     return run
@@ -702,7 +838,6 @@ def lower_pallas(
     op: "AssocOp | str | None" = None,
     *,
     axis_names: Optional[Sequence[str]] = None,
-    interpret: Optional[bool] = None,
     traced: bool = False,
 ):
     """Compile a supported plan to fused-Pallas-kernel schedules.
@@ -714,10 +849,11 @@ def lower_pallas(
     single device. Outputs are bitwise identical to the op-per-round
     lowerings (same arithmetic, same operand order, same zero-fills).
 
-    ``interpret=None`` auto-selects Pallas interpret mode off-TPU so CI
-    exercises the DMA structure on CPU. Raises ``ValueError`` for plans
-    outside :func:`supports_plan`; callers wanting a soft fallback go
-    through the lowering registry (``repro.offload.backends``).
+    The kernels run natively on a TPU and in Pallas interpret mode
+    anywhere else, so CI exercises the DMA structure on CPU. Raises
+    ``ValueError`` for plans outside :func:`supports_plan`; callers wanting
+    a soft fallback go through the lowering registry
+    (``repro.offload.backends``).
     """
     op = get_operator(plan.op_name if op is None else op)
     ok, reason = supports_plan(plan, axis_names)
@@ -726,7 +862,7 @@ def lower_pallas(
             f"plan not supported by the pallas backend ({reason}); "
             f"use the registry default lowering"
         )
-    inter = _resolve_interpret(interpret)
+    inter = not on_tpu()
     if axis_names is None:
         return _lower_pallas_sim(plan, op, inter, traced)
     return _lower_pallas_spmd(plan, op, axis_names, inter)

@@ -10,12 +10,12 @@ chunks across devices with the offloaded scan collective.
 
 Time is mapped to the TPU *lane* axis within a tile (contiguous, 128-aligned)
 and the (batch×feature) rows to sublanes; the in-tile pair scan is a
-log2(tile) shift/multiply ladder on the VPU.
+log2(tile) Hillis–Steele ladder of lane rotations (``pltpu.roll``) and
+multiply-adds on the VPU, the wrapped lanes masked to the pair identity
+(1, 0).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -24,13 +24,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _pair_combine(left, right):
-    al, bl = left
-    ar, br = right
-    return ar * al, ar * bl + br
+def _lane_pair_scan(a, b):
+    """Inclusive scan of the pairs ``(a, b)`` under ``(al, bl) . (ar, br) =
+    (ar * al, ar * bl + br)`` along the lane axis of 2-D tiles, in f32
+    (Mosaic rotates only 32-bit vectors)."""
+    if a.dtype != jnp.float32 or b.dtype != jnp.float32:
+        A, B = _lane_pair_scan(a.astype(jnp.float32), b.astype(jnp.float32))
+        return A.astype(a.dtype), B.astype(b.dtype)
+    n = a.shape[1]
+    lane = lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    d = 1
+    while d < n:
+        keep = lane >= d
+        a_prev = jnp.where(keep, pltpu.roll(a, d, 1), 1.0)
+        b_prev = jnp.where(keep, pltpu.roll(b, d, 1), 0.0)
+        a, b = a * a_prev, a * b_prev + b
+        d *= 2
+    return a, b
 
 
-def _ssd_kernel(a_ref, b_ref, h_ref, acc_ref, *, nblocks: int):
+def _ssd_kernel(a_ref, b_ref, h_ref, acc_ref):
     """One (BR, BT) tile of rows x time. acc holds (a_prod, h) carries."""
     j = pl.program_id(1)
 
@@ -42,7 +55,7 @@ def _ssd_kernel(a_ref, b_ref, h_ref, acc_ref, *, nblocks: int):
     a = a_ref[...]
     b = b_ref[...]
     # In-tile inclusive pair scan along time (axis 1).
-    A, B = lax.associative_scan(_pair_combine, (a, b), axis=1)
+    A, B = _lane_pair_scan(a, b)
     a_in = acc_ref[0, :, :1]
     h_in = acc_ref[1, :, :1]
     # Fold in carry: h_t = B_t + A_t * h_in ; decay product also accumulates.
@@ -50,7 +63,6 @@ def _ssd_kernel(a_ref, b_ref, h_ref, acc_ref, *, nblocks: int):
     h_ref[...] = h
     acc_ref[0, :, :1] = A[:, -1:] * a_in
     acc_ref[1, :, :1] = h[:, -1:]
-    del nblocks
 
 
 def ssd_scan_pallas(
@@ -75,9 +87,8 @@ def ssd_scan_pallas(
             f"shape {a.shape} not divisible by blocks ({block_rows},{block_time})"
         )
     grid = (rows // block_rows, t // block_time)
-    kernel = functools.partial(_ssd_kernel, nblocks=grid[1])
     h = pl.pallas_call(
-        kernel,
+        _ssd_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, block_time), lambda i, j: (i, j)),

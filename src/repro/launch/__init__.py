@@ -1,2 +1,7 @@
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh, production_topology
+from repro.launch.mesh import (
+    auto_mesh,
+    make_local_mesh,
+    make_production_mesh,
+    production_topology,
+)
 from repro.launch.offload_runtime import build_offload_engine, get_engine

@@ -51,6 +51,7 @@ import weakref
 from pathlib import Path
 from typing import List, Optional, Tuple
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import events as obs_events
 from repro.offload import (
     TUNING_TABLE_ENV,
@@ -443,6 +444,7 @@ def main() -> None:
         "(keyed by backend fingerprint) so other workers inherit it",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     if not (
         args.tune or args.trace or args.dashboard or args.serve is not None
     ):

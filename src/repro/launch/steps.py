@@ -27,10 +27,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.packet import CollType
 from repro.models import ModelApi, input_specs

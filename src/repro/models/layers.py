@@ -15,9 +15,8 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 
-from repro.compat import shard_map
 from repro.perf_flags import FLAGS as _DEFAULT_FLAGS
 from repro import perf_flags
 from repro.sharding import current_topology, shard

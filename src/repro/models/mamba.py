@@ -30,11 +30,10 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import perf_flags
-from repro.compat import axis_size as compat_axis_size, shard_map
 from repro.core import SSD, dist_exscan
 from repro.kernels.ops import prefix_scan
 from repro.sharding import current_topology, shard
@@ -235,7 +234,7 @@ def _mixer_core(p: Params, x: jax.Array, cfg, halo_x, state_in, seq_axis, tp):
     }
     if seq_axis is not None:
         # decode cache is global: take the LAST sequence shard's values
-        psize = compat_axis_size(seq_axis)
+        psize = lax.axis_size(seq_axis)
         last = lax.axis_index(seq_axis) == psize - 1
         cache = jax.tree.map(
             lambda a: lax.psum(jnp.where(last, a, jnp.zeros_like(a)), seq_axis),
@@ -278,7 +277,7 @@ def mamba_mixer(
     def region(p_l, x_l):
         # conv halo: last 3 raw tokens from the left sequence shard (rank 0
         # receives ppermute zero-fill == causal zero padding)
-        psize = compat_axis_size(axis)
+        psize = lax.axis_size(axis)
         tail = x_l[:, -(_CONV_WIDTH - 1):, :]
         halo_x = lax.ppermute(tail, axis, [(i, i + 1) for i in range(psize - 1)])
         return _mixer_core(p_l, x_l, cfg, halo_x, None, axis, False)

@@ -21,10 +21,9 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.kernels.ops import prefix_scan
 from repro.models.layers import _ACT
 from repro.sharding import current_topology

@@ -248,13 +248,11 @@ def dist_hierarchical_scan(
     but each phase's schedule only ever spans one axis — which is what keeps
     every hop on a physical ring of the 2D torus.
     """
-    from repro.compat import axis_size
-
     op = get_operator(op)
     axis_names = (outer_axis, inner_axis)
     plan = _two_level_plan(
         op,
-        (axis_size(outer_axis), axis_size(inner_axis)),
+        (jax.lax.axis_size(outer_axis), jax.lax.axis_size(inner_axis)),
         _payload_bytes(x),
         inclusive=inclusive,
         algorithms=(outer_algorithm, inner_algorithm),

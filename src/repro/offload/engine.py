@@ -934,8 +934,6 @@ class OffloadEngine:
         """
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
-
         names = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
         missing = [n for n in names if n not in mesh.axis_names]
         if missing:
@@ -964,7 +962,7 @@ class OffloadEngine:
             return jax.tree.map(lambda a: jnp.asarray(a)[None], out)
 
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(spec,),

@@ -136,6 +136,9 @@ class CollectivePlan:
     #: across exchange rounds (sPIN-style streaming); values are bitwise
     #: identical, only the round interleave changes.
     chunking: int = 1
+    #: per-rank payload bytes the plan was built for (0 = not given);
+    #: backends whose capability depends on size read it
+    payload_bytes: int = 0
 
     @property
     def logical_sizes(self) -> Tuple[int, ...]:
@@ -543,6 +546,7 @@ def build_plan(
         order=order,
         phases=phases,
         result=result,
+        payload_bytes=int(payload_bytes),
     )
     if optimize:
         from repro.offload.passes import optimize_plan

@@ -5,8 +5,9 @@ inside ``shard_map`` (spmd mode) it "leaves latency to the profiler". This
 module closes that loop: one dispatch runs under ``jax.profiler`` with a
 :class:`jax.profiler.TraceAnnotation` naming the schedule, the emitted
 ``*.trace.json.gz`` chrome trace is parsed with the stdlib (no tensorboard
-dependency), and the *device-side execution time* inside the annotation
-window — the union of XLA executable-run event intervals, so nested events
+dependency), and the *device-side execution time* of that dispatch — the
+union of the device timeline's event intervals (on the CPU backend, of the
+executable-run events inside the annotation window), so nested events
 never double-count — is recorded into
 :class:`~repro.offload.engine.EngineTelemetry` as a **measured-on-device**
 latency source, distinct from the wall-clock numbers. That is the software
@@ -103,33 +104,48 @@ def _interval_union_us(intervals: List[Tuple[float, float]]) -> float:
 def parse_device_us(
     trace_path: str, annotation: str
 ) -> Optional[Tuple[float, int]]:
-    """(device µs, event count) for one annotation window, or None.
+    """(device µs, event count) for the one dispatch a trace holds, or None.
 
     Reads the chrome-trace JSON jax writes next to its xplane protobuf.
-    The annotation's complete event bounds the window; device time is the
-    interval union of executable-execution events overlapping it (clipped
-    to the window), so nested Execute/ExecuteHelper pairs count once.
+    Device time is an interval union, so nested events count once.
+
+    * An accelerator trace has a ``/device:...`` process, the device's own
+      timeline. Every event on it counts, unclipped: the session brackets
+      exactly one dispatch (:func:`profile_offload` blocks on its inputs
+      and warmup before starting it), and the device clock is aligned to
+      the host's only approximately, so clipping to the host annotation
+      could drop the whole dispatch.
+    * A trace without one (the CPU backend) times the host's
+      executable-execution events inside the annotation's window.
     """
     try:
         trace = json.loads(gzip.open(trace_path, "rb").read())
     except (OSError, ValueError):
         return None
     events = trace.get("traceEvents", [])
-    window: Optional[Tuple[float, float]] = None
-    for e in events:
-        if e.get("ph") == "X" and e.get("name") == annotation:
-            t0 = float(e.get("ts", 0.0))
-            window = (t0, t0 + float(e.get("dur", 0.0)))
-            break
-    if window is None:
-        return None
-    lo_w, hi_w = window
+    device_pids = {
+        e.get("pid")
+        for e in events
+        if e.get("ph") == "M"
+        and e.get("name") == "process_name"
+        and str(e.get("args", {}).get("name", "")).startswith("/device:")
+    }
+    spans = [e for e in events if e.get("ph") == "X"]
+    if device_pids:
+        chosen = [e for e in spans if e.get("pid") in device_pids]
+        lo_w, hi_w = float("-inf"), float("inf")
+    else:
+        window = next((e for e in spans if e.get("name") == annotation), None)
+        if window is None:
+            return None
+        lo_w = float(window.get("ts", 0.0))
+        hi_w = lo_w + float(window.get("dur", 0.0))
+        chosen = [
+            e for e in spans
+            if _DEVICE_EVENT_RE.search(str(e.get("name", "")))
+        ]
     intervals: List[Tuple[float, float]] = []
-    for e in events:
-        if e.get("ph") != "X" or not _DEVICE_EVENT_RE.search(
-            str(e.get("name", ""))
-        ):
-            continue
+    for e in chosen:
         lo = float(e.get("ts", 0.0))
         hi = lo + float(e.get("dur", 0.0))
         lo, hi = max(lo, lo_w), min(hi, hi_w)
@@ -163,8 +179,13 @@ def profile_offload(
 
     desc = engine._as_descriptor(descriptor)
     coll = desc.coll_type.name.lower()
+    # the session must hold this one dispatch alone: nothing earlier may
+    # still be running on the device when it starts
+    jax.block_until_ready(x)
     for _ in range(max(0, warmup)):
-        engine.offload(desc, x, axis_name=axis_name, mesh=mesh)
+        jax.block_until_ready(
+            engine.offload(desc, x, axis_name=axis_name, mesh=mesh)
+        )
     tag = f"{ANNOTATION_PREFIX}:{coll}:p{desc.comm_size}"
     owned = trace_dir is None
     tmp = tempfile.mkdtemp(prefix="repro_prof_") if owned else trace_dir

@@ -34,6 +34,7 @@ from typing import Any, Callable, Dict, Iterator, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import ModelConfig, ShapeConfig
@@ -61,8 +62,7 @@ class TrainerConfig:
     log_every: int = 10
     async_ckpt: bool = True
     #: route the step's gradient/metric collectives through the offload
-    #: engine as planned descriptors (requires a pure-DP mesh; a no-op
-    #: without a mesh)
+    #: engine as planned descriptors (requires a pure-DP mesh)
     use_offload_engine: bool = False
 
 
@@ -94,9 +94,12 @@ class Trainer:
         self._build()
 
     def _build(self):
-        use_engine = (
-            self.tcfg.use_offload_engine and self.topo.mesh is not None
-        )
+        use_engine = self.tcfg.use_offload_engine
+        if use_engine and self.topo.mesh is None:
+            raise ValueError(
+                "use_offload_engine needs a mesh to dispatch over; "
+                "build the Trainer with a meshed topology"
+            )
         if use_engine and self.engine is None:
             from repro.launch.offload_runtime import build_offload_engine
 
@@ -111,6 +114,14 @@ class Trainer:
         with use_topology(self.topo):
             params = self.api.init(jax.random.key(seed))
             opt_state = init_opt_state(params)
+        if self.topo.mesh is not None:
+            # commit the state to the shardings the step hands it back
+            # with: uncommitted state compiles the step again on call two
+            shardings = jax.tree.map(
+                lambda s: NamedSharding(self.topo.mesh, s), self.specs[:2],
+                is_leaf=lambda x: isinstance(x, PartitionSpec),
+            )
+            params, opt_state = jax.device_put((params, opt_state), shardings)
         return params, opt_state
 
     def maybe_restore(self, params, opt_state):

@@ -15,7 +15,7 @@ import sys  # noqa: E402
 from functools import partial  # noqa: E402
 
 import jax  # noqa: E402
-from repro.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402

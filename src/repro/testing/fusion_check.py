@@ -35,7 +35,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
-from repro.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from repro.core import CollType, sim_reduce, sim_scan  # noqa: E402
 from repro.offload import (  # noqa: E402
     OffloadEngine,

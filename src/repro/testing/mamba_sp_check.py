@@ -20,6 +20,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
+from repro.launch.mesh import auto_mesh  # noqa: E402
 from repro.models.mamba import init_mamba, mamba_mixer  # noqa: E402
 from repro.sharding.specs import make_topology, use_topology  # noqa: E402
 
@@ -34,7 +35,7 @@ def main() -> None:
 
     y_ref, cache_ref = mamba_mixer(p, x, cfg, seq_parallel=False)
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = auto_mesh((1, 8), ("data", "model"))
     topo = make_topology(mesh)
     with use_topology(topo):
         y_sp, cache_sp = jax.jit(

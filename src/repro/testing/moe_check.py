@@ -19,6 +19,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
+from repro.launch.mesh import auto_mesh  # noqa: E402
 from repro.models.moe import _dense_moe, init_moe, moe_block  # noqa: E402
 from repro.sharding.specs import make_topology, use_topology  # noqa: E402
 
@@ -38,7 +39,7 @@ def main() -> None:
 
     want, aux_want = _dense_moe(p, x, cfg, "silu")
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     topo = make_topology(mesh)
     with use_topology(topo):
         got, aux_got = jax.jit(lambda pp, xx: moe_block(pp, xx, cfg, act="silu"))(p, x)

@@ -33,7 +33,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
-from repro.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from repro.core import SSD  # noqa: E402
 from repro.core.operators import get_operator  # noqa: E402
 from repro.kernels import pallas_collective  # noqa: E402
@@ -89,9 +89,7 @@ def _run_pair(mesh, plan, op, x):
 
     ref_fn = wrap(lower_spmd(plan, ("i",), op))
     got_fn = wrap(
-        pallas_collective.lower_pallas(
-            plan, op, axis_names=("i",), interpret=True
-        )
+        pallas_collective.lower_pallas(plan, op, axis_names=("i",))
     )
     args = (x,) if x is not None else ()
     return ref_fn(*args), got_fn(*args)

@@ -31,7 +31,7 @@ from repro.offload import (
     optimize_plan,
     select_chunking,
 )
-from repro.testing.hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 MESHES = [(8,), (2, 4), (4, 2), (2, 2, 2), (2, 2, 4), (2, 8)]
 CHUNKS = (1, 2, 4, 8)

@@ -397,6 +397,53 @@ def test_profiler_fallback_reason_is_counted(monkeypatch):
     ) in obs_metrics.render_prometheus()
 
 
+@pytest.mark.parametrize(
+    "device_plane,offset",
+    [(True, 0.0), (True, 5000.0), (False, 0.0)],
+    ids=["tpu", "tpu_clock_offset", "cpu"],
+)
+def test_parse_device_us_reads_the_device_timeline(
+    tmp_path, device_plane, offset
+):
+    """An accelerator trace is timed from its /device: process alone (host
+    events that happen to match the executable regex do not count), all of
+    it: the session holds one dispatch, and a device clock that is offset
+    from the host's must not push it out of the annotation's window. A
+    trace without one falls back to the host's executable events inside
+    the window."""
+    import gzip
+    import json
+
+    from repro.offload import parse_device_us
+
+    events = [
+        {"ph": "M", "name": "process_name", "pid": 1,
+         "args": {"name": "/host:CPU"}},
+        {"ph": "X", "name": "repro_offload:scan:p8", "pid": 1,
+         "ts": 100.0, "dur": 100.0},
+        {"ph": "X", "name": "TfrtCpuExecutable::Execute", "pid": 1,
+         "ts": 110.0, "dur": 50.0},
+    ]
+    if device_plane:
+        events += [
+            {"ph": "M", "name": "process_name", "pid": 7,
+             "args": {"name": "/device:TPU:0"}},
+            {"ph": "X", "name": "jit_scan", "pid": 7, "ts": 120.0 + offset,
+             "dur": 30.0},
+            {"ph": "X", "name": "fusion.1", "pid": 7, "ts": 125.0 + offset,
+             "dur": 10.0},
+            {"ph": "X", "name": "jit_other", "pid": 7, "ts": 190.0 + offset,
+             "dur": 40.0},
+        ]
+    path = tmp_path / "t.trace.json.gz"
+    path.write_bytes(gzip.compress(json.dumps(
+        {"traceEvents": events}
+    ).encode()))
+    got = parse_device_us(str(path), "repro_offload:scan:p8")
+    # device: [120,150) and [190,230), unclipped; host: [110,160)
+    assert got == ((70.0, 3) if device_plane else (50.0, 1))
+
+
 # ------------------------------------------------------ latency histogram
 
 

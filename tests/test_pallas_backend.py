@@ -99,6 +99,29 @@ def test_non_zero_identity_ops_rejected(opname):
     assert b.name == "sim" and reason == "op_flags"
 
 
+def test_plan_without_payload_size_refused():
+    """The VMEM gate prices the declared payload: a plan that declares none
+    cannot be priced, so it is refused rather than admitted."""
+    plan = dataclasses.replace(build_plan("SCAN", (P,), SUM, 4 * N),
+                               payload_bytes=0)
+    assert pallas_collective.supports_plan(plan) == (False, "payload_unknown")
+
+
+def test_multi_leaf_payload_over_vmem_raises_before_compile():
+    """At the largest admitted one-leaf payload (4 MiB/rank at p=8, the
+    whole budget), the same bytes split over two leaves fit, but twice them
+    do not: the kernel checks the tiles it builds and raises instead of
+    handing Mosaic a kernel over its VMEM limit."""
+    mib4 = 4 * 2**20
+    plan = build_plan("SCAN", (P,), SUM, mib4)
+    assert pallas_collective.supports_plan(plan) == (True, "")
+    fn = backends.get_backend("pallas").lower(plan, SUM)
+    leaf = lambda n: jax.ShapeDtypeStruct((P, n), jnp.float32)  # noqa: E731
+    jax.eval_shape(fn, {"a": leaf(mib4 // 8), "b": leaf(mib4 // 8)})
+    with pytest.raises(ValueError, match="bytes of VMEM for 2 packed"):
+        jax.eval_shape(fn, {"a": leaf(mib4 // 4), "b": leaf(mib4 // 4)})
+
+
 # ------------------------------------------------------- sim-form bitwise
 
 

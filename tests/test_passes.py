@@ -38,7 +38,7 @@ from repro.offload import (
     tune_fusion,
 )
 from repro.service import DescriptorBroker
-from repro.testing.hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 MESHES = [(2, 4), (4, 2), (2, 2), (3, 2), (2, 2, 2), (2, 3, 2), (1, 4),
           (2, 1, 2), (4,), (1,)]

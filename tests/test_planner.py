@@ -38,7 +38,7 @@ from repro.offload import (
     tune_splits,
 )
 from repro.sharding.specs import plan_spec
-from repro.testing.hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 MESHES_2D = [(2, 4), (4, 2), (3, 3), (2, 2)]
 MESHES_3D = [(2, 2, 2), (2, 3, 2), (3, 2, 2)]

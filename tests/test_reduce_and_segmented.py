@@ -4,7 +4,7 @@ coll_types (Reduce/Allreduce/Barrier) on the same schedule machinery."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from repro.testing.hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import ALGORITHMS, MAX, SUM, segmented_operator, sim_scan
 

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from repro.testing.hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     ALGORITHMS,
@@ -61,8 +61,12 @@ def test_sum_matches_cumsum(p, n, algo, inclusive, data):
 @settings(max_examples=25, deadline=None)
 @given(p=st.integers(1, 17), algo=st.sampled_from(GENERIC_ALGOS), data=st.data())
 def test_max_scan(p, algo, data):
+    # XLA's CPU backend flushes subnormals to zero; numpy keeps them
     vals = data.draw(
-        st.lists(st.floats(-100, 100, width=32), min_size=p, max_size=p)
+        st.lists(
+            st.floats(-100, 100, width=32, allow_subnormal=False),
+            min_size=p, max_size=p,
+        )
     )
     x = np.asarray(vals, np.float32)[:, None]
     want = np.maximum.accumulate(x, axis=0)

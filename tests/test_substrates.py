@@ -4,7 +4,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from repro.testing.hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.data.pipeline import DataConfig, batches, pack_documents
 from repro.checkpoint.manager import CheckpointManager

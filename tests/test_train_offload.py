@@ -47,6 +47,29 @@ def test_build_train_step_flag_noop_without_mesh():
     assert step is not None  # fell back to the jitted GSPMD path
 
 
+def test_trainer_engine_without_mesh_raises(tmp_path):
+    """The Trainer refuses the engine without a mesh instead of dropping it."""
+    from repro.runtime.train_loop import Trainer, TrainerConfig
+
+    api = build_model(get_config("smollm_360m").reduced())
+    shape = ShapeConfig("tiny", 16, 4, "train")
+    with pytest.raises(ValueError, match="needs a mesh"):
+        Trainer(
+            api, Topology(mesh=None), shape, iter(()),
+            TrainerConfig(ckpt_dir=str(tmp_path), use_offload_engine=True),
+        )
+
+
+def test_train_cli_mesh_and_width_flags():
+    """--full is the only way to published widths; --mesh local exists."""
+    from repro.launch.train import parse_args
+
+    args = parse_args(["--arch", "mamba2-130m"])
+    assert args.reduced and args.mesh == "none"
+    args = parse_args(["--arch", "mamba2-130m", "--full", "--mesh", "local"])
+    assert not args.reduced and args.mesh == "local"
+
+
 def test_dp_step_rejects_tensor_parallel_mesh():
     cfg = get_config("smollm_360m").reduced()
     api = build_model(cfg)
@@ -69,3 +92,18 @@ def test_make_topology_pure_dp_pod_mesh():
     assert topo.model_axis is None
     assert topo.model_size == 1
     assert topo.dp_size == 1
+
+
+def test_trainer_step_compiles_once(tmp_path):
+    """The state init_state hands out carries the shardings the step
+    returns it with, so the second step reuses the first step's program."""
+    from repro.launch import train
+
+    args = train.parse_args([
+        "--arch", "mamba2-130m", "--mesh", "local", "--steps", "2",
+        "--batch", "2", "--seq", "32", "--ckpt-dir", str(tmp_path),
+    ])
+    tr = train.build_trainer(args)
+    params, opt = tr.init_state()
+    tr.run(params, opt, 2)
+    assert tr.step_fn._cache_size() == 1
