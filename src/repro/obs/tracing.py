@@ -7,23 +7,49 @@ schedule-cache lookup, compilation, and the per-round host constant of the
 sim interpreter — so this module provides lightweight host-side spans with
 explicit parent links covering the full request lifecycle:
 
-    service.submit  ->  broker.queue_wait  ->  broker.dispatch_group
+    service.submit  ->  broker.queue_wait                (client thread)
+    broker.idle | broker.flush_wait                      (dispatch thread)
+    broker.dispatch_group                                (dispatch thread)
+      ->  broker.stack                 (pad + stack a fused group)
       ->  engine.offload (cache hit/miss, engine.compile on miss)
-        ->  plan.phase:<KIND>:L<level>   (one per PlanPhase)
-          ->  plan.round:<i>             (one per communication round)
+        ->  engine.launch              (the compiled schedule's dispatch)
+          ->  plan.phase:<KIND>:L<level>   (traced plans: one per PlanPhase)
+            ->  plan.round:<i>             (one per communication round)
+        ->  engine.device_wait         (block_until_ready on its output)
+      ->  broker.unstack               (one slice per fused request)
+      ->  broker.fulfil                (telemetry + ticket fulfilment)
+
+``broker.queue_wait`` carries ``request="<tenant>#<seqno>"`` and
+``broker.dispatch_group`` the list of its requests' ids (``requests``), so
+a request's wait and the group that served it share one identifier.
+``engine.offload`` carries the schedule's ``algo``, the payload's
+``bytes_per_rank`` and its communication ``rounds`` (counted once, at
+compile time: ``CompiledSchedule.rounds``).
 
 Span categories (``cat``): ``service``, ``broker``, ``engine``, ``phase``,
-``round``, and — in link-probe mode (``Tracer(link_probe=True)``, see
-:mod:`repro.obs.health`) — ``link``, one span per (src, dst) message of a
-round. Timestamps are ``time.perf_counter()`` microseconds, one
-monotonic clock for the whole process, so spans from every thread land on
-one timeline; :mod:`repro.obs.export` serializes them to Chrome/Perfetto
-trace JSON and can merge the device-side events a ``jax.profiler`` trace
-records for the same dispatch.
+``round``, ``profile``, and — in link-probe mode
+(``Tracer(link_probe=True)``, see :mod:`repro.obs.health`) — ``link``, one
+span per (src, dst) message of a round. Timestamps are
+``time.perf_counter()`` microseconds, one monotonic clock for the whole
+process, so spans from every thread land on one timeline;
+:mod:`repro.obs.export` serializes them to Chrome/Perfetto trace JSON and
+can merge the device-side events a ``jax.profiler`` trace records for the
+same dispatch.
+
+**The profiler's clock.** A collecting tracer's context spans
+(:meth:`Tracer.span`) also hold a ``jax.profiler.TraceAnnotation`` of the
+same name open while they run, so every one of them appears as a host
+event in any ``jax.profiler`` trace taken meanwhile, on the profiler's own
+clock, beside the device's events: an idle gap of the device can be named
+by the program step open over it. Spans recorded after the fact with
+:meth:`Tracer.add_span` (``service.submit``, ``broker.queue_wait``, the
+fused-kernel phase and round spans) cannot be annotated retroactively and
+appear in the tracer alone. ``Span.start_us`` stays on ``perf_counter``.
 
 **Tracing is off by default and zero-cost when off.** The module-level
 tracer is a :class:`NoopTracer` whose ``span()`` returns one shared no-op
-context manager — instrumented code paths pay a single attribute check.
+context manager — instrumented code paths pay a single attribute check,
+and no profiler annotation is made.
 Nothing about the dispatched computation changes either way: spans only
 ever wrap *host-side* work. Jitted code paths (driver/spmd dispatch) get
 spans around the dispatch, never inside traced computations; only the
@@ -180,6 +206,10 @@ class Tracer:
         self.link_probe = bool(link_probe)
         self.link_injector = link_injector
         self.link_detector = link_detector
+        import jax
+
+        # the span's profiler-trace twin: a host event of the same name
+        self._annotate = jax.profiler.TraceAnnotation
 
     # -- recording ---------------------------------------------------------
 
@@ -210,7 +240,8 @@ class Tracer:
         )
         stack.append(handle.span_id)
         try:
-            yield handle
+            with self._annotate(name):
+                yield handle
         finally:
             stack.pop()
             self._append(
@@ -325,7 +356,10 @@ class TracingBackend:
     transfer, sync — the per-round constant the ROADMAP wall-clock item
     wants attributed. Only meaningful on the eager sim backend: inside jit
     there is no per-round host work to measure, and this wrapper must never
-    be used there.
+    be used there to time. Over the no-op tracer it only counts: the engine
+    traces a schedule through it once, at compile time (``jax.eval_shape``,
+    where there is nothing to block on), and keeps ``rounds`` as the
+    schedule's round count (``CompiledSchedule.rounds``).
 
     Chunked-streaming schedules (:func:`repro.core.algorithms._pipeline`)
     announce the (chunk, schedule-round) coordinates of each pipeline slot

@@ -150,7 +150,14 @@ def wire_dtype(dt: WireDType):
 
 @dataclasses.dataclass
 class EngineTelemetry:
-    """Counters the engine maintains per dispatch (the NIC status registers)."""
+    """Counters the engine maintains per dispatch (the NIC status registers).
+
+    ``rounds_dispatched`` is the running sum, over every dispatch, of the
+    communication rounds its compiled schedule issues
+    (:attr:`CompiledSchedule.rounds`, counted once at compile time); over a
+    window it equals the sum of the ``rounds`` args on that window's
+    ``engine.offload`` spans.
+    """
 
     hits: int = 0
     misses: int = 0
@@ -180,9 +187,13 @@ class EngineTelemetry:
     backend_fallback_reasons: Dict[str, int] = dataclasses.field(
         default_factory=dict
     )
+    rounds_dispatched: int = 0
 
-    def record_dispatch(self, coll: str, latency_s: Optional[float]) -> None:
+    def record_dispatch(
+        self, coll: str, latency_s: Optional[float], rounds: int = 0
+    ) -> None:
         self.dispatches += 1
+        self.rounds_dispatched += rounds
         self.calls_by_coll[coll] = self.calls_by_coll.get(coll, 0) + 1
         reg = obs_metrics.get_registry()
         reg.counter(
@@ -302,12 +313,20 @@ class EngineTelemetry:
             "profiler_fallback_reasons": dict(self.profiler_fallback_reasons),
             "backend_fallbacks": self.backend_fallbacks,
             "backend_fallback_reasons": dict(self.backend_fallback_reasons),
+            "rounds_dispatched": self.rounds_dispatched,
         }
 
 
 @dataclasses.dataclass(frozen=True)
 class CompiledSchedule:
-    """A cache entry: the closure that runs one descriptor's collective."""
+    """A cache entry: the closure that runs one descriptor's collective.
+
+    ``rounds`` is the number of communication rounds the schedule issues
+    per dispatch, counted once when it compiles: the ``permute`` calls the
+    op-per-round schedule makes while it is traced (summed over the phases
+    of a planned one), and for a fused-kernel phase the rounds the kernel
+    runs (:func:`repro.kernels.pallas_collective.kernel_round_structure`).
+    """
 
     key: bytes
     coll: str
@@ -315,6 +334,7 @@ class CompiledSchedule:
     op_name: str
     p: int
     fn: Callable[[PyTree], PyTree]
+    rounds: int = 0
 
 
 class OffloadEngine:
@@ -734,9 +754,16 @@ class OffloadEngine:
                 p=int(desc.comm_size),
                 traced_plan=traced,
             )
+        timed = axis_name is None or mesh is not None
+        if desc.coll_type == CollType.BARRIER:
+            if mesh is not None and x is None:
+                x = jnp.zeros((desc.comm_size,), jnp.float32)
+        elif timed:
+            self._validate_sim_payload(desc, x)
         sched = self._cache.get(key)
         if sched is None:
             tracer = obs_tracing.get_tracer() if span is not None else None
+            shape = _stacked_shape(x, desc.comm_size, stacked=timed)
             try:
                 if span is not None:
                     with tracer.span(
@@ -744,11 +771,13 @@ class OffloadEngine:
                         coll=desc.coll_type.name.lower(),
                     ):
                         sched = self._compile(
-                            desc, key, axis_name, mesh, traced=traced
+                            desc, key, axis_name, mesh, traced=traced,
+                            shape=shape,
                         )
                 else:
                     sched = self._compile(
-                        desc, key, axis_name, mesh, traced=traced
+                        desc, key, axis_name, mesh, traced=traced,
+                        shape=shape,
                     )
             except Exception:
                 self.telemetry.errors += 1
@@ -779,22 +808,25 @@ class OffloadEngine:
                 labelnames=("event",),
             ).inc(event="hit")
 
-        timed = axis_name is None or mesh is not None
-        if desc.coll_type == CollType.BARRIER:
-            if mesh is not None and x is None:
-                x = jnp.zeros((desc.comm_size,), jnp.float32)
-        elif timed:
-            self._validate_sim_payload(desc, x)
+        if span is not None:
+            span.set(
+                algo=sched.algo,
+                bytes_per_rank=_bytes_per_rank(x, desc.comm_size, timed),
+                rounds=sched.rounds,
+            )
 
         if timed:
+            tracer = obs_tracing.get_tracer()
             t0 = time.perf_counter()
-            out = sched.fn(x)
-            out = jax.tree.map(lambda a: a.block_until_ready(), out)
+            with tracer.span("engine.launch", "engine"):
+                out = sched.fn(x)
+            with tracer.span("engine.device_wait", "engine"):
+                out = jax.tree.map(lambda a: a.block_until_ready(), out)
             latency = time.perf_counter() - t0
         else:
             out = sched.fn(x)
             latency = None  # inside a trace: the profiler owns timing
-        self.telemetry.record_dispatch(sched.coll, latency)
+        self.telemetry.record_dispatch(sched.coll, latency, sched.rounds)
         obs_events.record(
             "dispatch",
             coll=sched.coll,
@@ -867,7 +899,11 @@ class OffloadEngine:
         mesh: Any = None,
         *,
         traced: bool = False,
+        shape: Optional[PyTree] = None,
     ) -> CompiledSchedule:
+        """Compile one descriptor's schedule; ``shape`` is the payload as
+        stacked ``(p, ...)`` shapes (``jax.ShapeDtypeStruct`` leaves), which
+        the round count traces the schedule with."""
         op = get_operator(wire_op_name(desc.operation))
         algo = desc.algo_type
         coll = desc.coll_type
@@ -879,10 +915,19 @@ class OffloadEngine:
             )
 
         if len(desc.axes) > 1:
+            plan = self._plans.get(key)
             fn, bname = self._build_planned(
-                desc, op, axis_name, plan=self._plans.get(key),
-                traced=traced,
+                desc, op, axis_name, plan=plan, traced=traced,
             )
+            if bname == "pallas":
+                from repro.kernels import pallas_collective
+
+                rounds = sum(
+                    r for _, r in
+                    pallas_collective.kernel_round_structure(plan)
+                )
+            else:
+                rounds = planner.count_rounds(plan, op, shape)
             algo = f"plan{desc.split}:{algo}"
             if desc.optimized:
                 algo = f"opt:{algo}"
@@ -906,6 +951,17 @@ class OffloadEngine:
             fn = self._build_spmd(coll, op, algo, one, root)
         else:
             fn = jax.jit(self._build_sim(coll, op, algo, p, root))
+        if len(desc.axes) <= 1:
+            # the spmd form runs the same schedule over named axes: count
+            # the permutes of its stacked twin
+            counter = obs_tracing.TracingBackend(
+                alg.SimBackend(p), obs_tracing.NOOP
+            )
+            jax.eval_shape(
+                self._build_sim(coll, op, algo, p, root, backend=counter),
+                shape,
+            )
+            rounds = counter.rounds
         if mesh is not None:
             fn = self._build_driver(desc, fn, axis_name, mesh)
         return CompiledSchedule(
@@ -915,6 +971,7 @@ class OffloadEngine:
             op_name=op.name,
             p=p,
             fn=fn,
+            rounds=rounds,
         )
 
     @staticmethod
@@ -1020,24 +1077,28 @@ class OffloadEngine:
 
     @staticmethod
     def _build_sim(
-        coll: CollType, op: AssocOp, algo: str, p: int, root: int
+        coll: CollType, op: AssocOp, algo: str, p: int, root: int,
+        backend: Optional[alg.Backend] = None,
     ) -> Callable[[PyTree], PyTree]:
+        """The stacked schedule; ``backend`` (default a ``SimBackend(p)``)
+        may wrap one, as the compile-time round counter does."""
+        b = alg.SimBackend(p) if backend is None else backend
         if coll == CollType.SCAN:
-            return lambda x: sim_scan(x, op, p, algorithm=algo, inclusive=True)
+            return lambda x: sim_scan(
+                x, op, p, algorithm=algo, inclusive=True, backend=b
+            )
         if coll == CollType.EXSCAN:
             return lambda x: sim_scan(
-                x, op, p, algorithm=algo, inclusive=False
+                x, op, p, algorithm=algo, inclusive=False, backend=b
             )
         if coll == CollType.REDUCE:
             return lambda x: reduce_schedule(
-                alg.SimBackend(p), x, op, root=root, algorithm=algo
+                b, x, op, root=root, algorithm=algo
             )
         if coll == CollType.ALLREDUCE:
-            return lambda x: allreduce_schedule(
-                alg.SimBackend(p), x, op, algorithm=algo
-            )
+            return lambda x: allreduce_schedule(b, x, op, algorithm=algo)
         if coll == CollType.BARRIER:
-            return lambda _x: barrier_schedule(alg.SimBackend(p), algorithm=algo)
+            return lambda _x: barrier_schedule(b, algorithm=algo)
         raise ValueError(f"unknown coll_type {coll!r}")
 
     @staticmethod
@@ -1061,3 +1122,24 @@ class OffloadEngine:
                 alg.SpmdBackend(axis_name), algorithm=algo
             )
         raise ValueError(f"unknown coll_type {coll!r}")
+
+
+def _stacked_shape(x: PyTree, p: int, *, stacked: bool) -> PyTree:
+    """``x`` as ``jax.ShapeDtypeStruct`` leaves with the leading rank axis
+    of the sim contract (added when ``x`` is one rank's shard)."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            tuple(jnp.shape(a)) if stacked else (p,) + tuple(jnp.shape(a)),
+            jnp.result_type(a),
+        ),
+        x,
+    )
+
+
+def _bytes_per_rank(x: PyTree, p: int, stacked: bool) -> int:
+    """Payload bytes one rank contributes (0 without a payload)."""
+    total = sum(
+        int(np.prod(jnp.shape(a))) * jnp.dtype(jnp.result_type(a)).itemsize
+        for a in jax.tree.leaves(x)
+    )
+    return total // p if stacked else total

@@ -844,11 +844,24 @@ def _zero_coord_mask(
     return mask
 
 
+def count_rounds(
+    plan: CollectivePlan, op: "AssocOp | str | None", x: Any
+) -> int:
+    """Communication rounds ``plan`` issues on a payload shaped like ``x``
+    (stacked ``(p, ...)`` shapes): the ``permute`` calls each phase of its
+    op-per-round schedule makes while it is traced, summed over phases.
+    Nothing runs; chunked phases count every pipelined permute."""
+    counts = []
+    jax.eval_shape(lower_sim(plan, op, phase_rounds=counts.append), x)
+    return sum(counts)
+
+
 def lower_sim(
     plan: CollectivePlan,
     op: "AssocOp | str | None" = None,
     *,
     traced: bool = False,
+    phase_rounds: Optional[Callable[[int], None]] = None,
 ):
     """Compile a plan to a function over flat stacked ``(p, ...)`` leaves.
 
@@ -884,6 +897,11 @@ def lower_sim(
     makes permute elimination COMBINE-aware). Both interpreters compute
     identical values (``moveaxis`` is exact), so optimization never changes
     bits.
+
+    ``phase_rounds``, when given, is called after each communication phase
+    with the rounds (permutes) it issued, counted by a
+    :class:`repro.obs.tracing.TracingBackend` over the no-op tracer:
+    :func:`count_rounds` traces the schedule with it.
     """
     op = get_operator(plan.op_name if op is None else op)
     logical = plan.logical_sizes
@@ -1014,6 +1032,10 @@ def lower_sim(
                         obs_metrics.observe_round(coll_name, _k, idx, dur_us)
                     ),
                 )
+            elif phase_rounds is not None:
+                from repro.obs import tracing as obs_tracing
+
+                backend = obs_tracing.TracingBackend(backend, obs_tracing.NOOP)
             if ph.kind == PhaseKind.SCAN:
                 if chunks > 1:
                     fn = lambda t: _sim_scan_chunked(  # noqa: E731
@@ -1068,6 +1090,8 @@ def lower_sim(
                     set_reg(ph.dst2, out[1], None)
                 else:
                     set_reg(ph.dst, out, None)
+            if phase_rounds is not None:
+                phase_rounds(backend.rounds)
             if tracer is not None:
                 phase_span.set(rounds=getattr(backend, "rounds", 0))
                 phase_cm.__exit__(None, None, None)

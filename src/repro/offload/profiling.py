@@ -2,8 +2,8 @@
 
 In sim and driver mode the engine times dispatches with a host wall clock;
 inside ``shard_map`` (spmd mode) it "leaves latency to the profiler". This
-module closes that loop: one dispatch runs under ``jax.profiler`` with a
-:class:`jax.profiler.TraceAnnotation` naming the schedule, the emitted
+module closes that loop: one dispatch runs under ``jax.profiler`` with an
+annotation naming the schedule, the emitted
 ``*.trace.json.gz`` chrome trace is parsed with the stdlib (no tensorboard
 dependency), and the *device-side execution time* of that dispatch — the
 union of the device timeline's event intervals (on the CPU backend, of the
@@ -25,17 +25,16 @@ and the *reason* for the degradation is recorded
 silently stopped producing traces shows up on a dashboard instead of
 quietly substituting wall numbers.
 
-When a collecting tracer is installed (:mod:`repro.obs.tracing`), the
-profiled dispatch additionally emits a host-side span *named exactly like
-the TraceAnnotation tag*. The same name then appears in both the host span
-trace and the profiler's chrome trace, which is the anchor
+The annotation is a tracer span (:mod:`repro.obs.tracing`) named by the
+tag, which a collecting tracer's spans hold open as a profiler annotation:
+when a collecting tracer is installed the same name appears in both the
+host span trace and the profiler's chrome trace, which is the anchor
 :func:`repro.obs.export.merge_device_trace` uses to align the two clocks
 into one host+device timeline.
 """
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import glob
 import gzip
@@ -74,11 +73,6 @@ class DeviceTiming:
     #: concurrent profiler session), "stop_failed", "no_trace_file", or
     #: "parse_failed"; None when the profiler delivered
     fallback_reason: Optional[str] = None
-
-
-@contextlib.contextmanager
-def _noop():
-    yield
 
 
 def _newest_trace_file(trace_dir: str) -> Optional[str]:
@@ -192,7 +186,12 @@ def profile_offload(
     parsed: Optional[Tuple[float, int]] = None
     trace_path: Optional[str] = None
     fallback_reason: Optional[str] = None
+    # the tag's span is the trace's annotation and, in the active tracer,
+    # the host span merge_device_trace aligns the clocks on; with tracing
+    # off a private tracer still makes the annotation
     span_tracer = obs_tracing.get_tracer()
+    if not span_tracer.enabled:
+        span_tracer = obs_tracing.Tracer()
     try:
         # trace machinery failures (a concurrent profiler session, a
         # backend without the chrome export) degrade to the wall-clock
@@ -204,21 +203,12 @@ def profile_offload(
             tracing = False
             fallback_reason = "trace_start_failed"
         t0 = time.perf_counter()
-        t0_us = obs_tracing.now_us()
         try:
-            with jax.profiler.TraceAnnotation(tag) if tracing else _noop():
+            with span_tracer.span(tag, "profile", coll=coll, annotation=True):
                 out = engine.offload(desc, x, axis_name=axis_name, mesh=mesh)
                 jax.tree.map(lambda a: a.block_until_ready(), out)
         finally:
             wall_us = (time.perf_counter() - t0) * 1e6
-            if span_tracer.enabled:
-                # host span named exactly like the TraceAnnotation tag —
-                # the clock-alignment anchor for merge_device_trace
-                span_tracer.add_span(
-                    tag, "profile", t0_us, obs_tracing.now_us(),
-                    parent_id=span_tracer.current_span_id(),
-                    coll=coll, annotation=True,
-                )
             if tracing:
                 try:
                     jax.profiler.stop_trace()

@@ -163,6 +163,11 @@ class _Request:
         )
 
 
+def _request_id(req: _Request) -> str:
+    """The id a request's spans share: ``<tenant>#<seqno>``."""
+    return f"{req.tenant}#{req.ticket.seqno}"
+
+
 def _payload_signature(x: PyTree) -> Optional[Tuple]:
     if x is None:
         return None
@@ -480,9 +485,11 @@ class DescriptorBroker:
 
     def _loop(self) -> None:
         while True:
+            tracer = obs_tracing.get_tracer()
             with self._cond:
                 while not self._queue and not self._stopping:
-                    self._cond.wait()
+                    with tracer.span("broker.idle", "broker"):
+                        self._cond.wait()
                 if self._stopping:
                     return
                 # the deadline flush: wait until the oldest queued request's
@@ -490,7 +497,10 @@ class DescriptorBroker:
                 wakeup = min(r.flush_at for r in self._queue)
                 delay = wakeup - time.monotonic()
                 if delay > 0:
-                    self._cond.wait(delay)
+                    with tracer.span(
+                        "broker.flush_wait", "broker", queued=len(self._queue)
+                    ):
+                        self._cond.wait(delay)
                     continue
             self._pump(force=False)
 
@@ -533,7 +543,6 @@ class DescriptorBroker:
         self, reqs: List[_Request], *, deadline: bool = False
     ) -> None:
         desc = reqs[0].desc
-        barrier = desc.coll_type == CollType.BARRIER
         start_t = time.monotonic()
         tracer = obs_tracing.get_tracer()
         if tracer.enabled:
@@ -547,14 +556,25 @@ class DescriptorBroker:
                     req.submit_us or dispatch_t0, dispatch_t0,
                     parent_id=req.submit_span_id,
                     tenant=req.tenant,
+                    request=_request_id(req),
                 )
-        group_cm = tracer.span(
+        with tracer.span(
             "broker.dispatch_group", "broker",
             coll=desc.coll_type.name.lower(),
             group=len(reqs),
             deadline=deadline,
-        )
-        group_cm.__enter__()
+        ) as group_span:
+            if tracer.enabled:
+                group_span.set(requests=[_request_id(r) for r in reqs])
+            outcomes = self._dispatch_outcomes(reqs)
+            with tracer.span("broker.fulfil", "broker"):
+                self._fulfil(reqs, outcomes, start_t, deadline=deadline)
+
+    def _dispatch_outcomes(
+        self, reqs: List[_Request]
+    ) -> List[Tuple[List[_Request], List[PyTree], Optional[BaseException]]]:
+        """Run one group; every failure is captured into the outcomes,
+        reported later through the tickets."""
         try:
             # the optimized flag shapes the compiled schedule, so a fused
             # group must agree on it. Normal grouping guarantees this (the
@@ -576,8 +596,20 @@ class DescriptorBroker:
                 outcomes = self._run_group_reliable(reqs)
         except Exception as e:  # noqa: BLE001 - reported through tickets
             outcomes = [(reqs, [None] * len(reqs), e)]
-        finally:
-            group_cm.__exit__(None, None, None)
+        return outcomes
+
+    def _fulfil(
+        self,
+        reqs: List[_Request],
+        outcomes: List[
+            Tuple[List[_Request], List[PyTree], Optional[BaseException]]
+        ],
+        start_t: float,
+        *,
+        deadline: bool,
+    ) -> None:
+        """Account the group and answer every ticket."""
+        desc = reqs[0].desc
         done_t = time.monotonic()
         any_err = any(err is not None for _, _, err in outcomes)
         self.telemetry.record_flush(len(reqs), 1, deadline=deadline)
@@ -644,7 +676,7 @@ class DescriptorBroker:
                 if r.checksum is not None:
                     _rel.verify_payload(
                         r.payload, r.checksum,
-                        request=f"{r.tenant}#{r.ticket.seqno}",
+                        request=_request_id(r),
                     )
             deadlines = [
                 r.deadline_at for r in reqs if r.deadline_at is not None
@@ -656,20 +688,23 @@ class DescriptorBroker:
         if barrier or len(reqs) == 1:
             out = dispatch(desc, reqs[0].payload)
             return [out] * len(reqs)
-        payloads = [r.payload for r in reqs]
-        if self.coalesce_pad_pow2:
-            width = 1 << (len(payloads) - 1).bit_length()
-            pad = jax.tree.map(jnp.zeros_like, payloads[0])
-            payloads += [pad] * (width - len(payloads))
-        stacked = jax.tree.map(
-            lambda *leaves: jnp.stack(leaves, axis=1),
-            *payloads,
-        )
+        tracer = obs_tracing.get_tracer()
+        with tracer.span("broker.stack", "broker"):
+            payloads = [r.payload for r in reqs]
+            if self.coalesce_pad_pow2:
+                width = 1 << (len(payloads) - 1).bit_length()
+                pad = jax.tree.map(jnp.zeros_like, payloads[0])
+                payloads += [pad] * (width - len(payloads))
+            stacked = jax.tree.map(
+                lambda *leaves: jnp.stack(leaves, axis=1),
+                *payloads,
+            )
         fused = dispatch(desc, stacked)
-        return [
-            jax.tree.map(lambda l, i=i: l[:, i], fused)
-            for i in range(len(reqs))
-        ]
+        with tracer.span("broker.unstack", "broker"):
+            return [
+                jax.tree.map(lambda l, i=i: l[:, i], fused)
+                for i in range(len(reqs))
+            ]
 
     def _run_group_reliable(
         self, reqs: List[_Request]

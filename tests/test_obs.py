@@ -9,6 +9,7 @@ module. The health stack (flight recorder, SLOs, link attribution) is
 covered by tests/test_health.py."""
 
 import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -538,6 +539,198 @@ def test_broker_request_spans_link_submit_to_dispatch():
     # the engine span belongs to the dispatch-group window
     engine = [s for s in spans if s.name == "engine.offload"]
     assert engine and engine[0].parent_id == group.span_id
+
+
+def _children(spans, parent):
+    return [s for s in spans if s.parent_id == parent.span_id]
+
+
+def test_broker_drain_nests_the_group_work_under_dispatch_group():
+    """A fused group: stack, the engine's launch and device wait, unstack
+    and fulfil all sit inside its dispatch_group, whose request ids are the
+    queue_wait spans' ones."""
+    with obs_tracing.tracing() as tracer:
+        broker = DescriptorBroker(OffloadEngine())
+        desc = broker.make_descriptor(
+            "SCAN", p=P, payload_bytes=N * 4, op="sum"
+        )
+        tickets = [
+            broker.client(t).submit(desc.encode(), _x(i))
+            for i, t in enumerate(("t0", "t1", "t2"))
+        ]
+        assert broker.drain() == 3
+        for t in tickets:
+            t.result(5)
+    spans = tracer.spans()
+    (group,) = [s for s in spans if s.name == "broker.dispatch_group"]
+    kids = {s.name: s for s in _children(spans, group)}
+    assert set(kids) == {
+        "broker.stack", "engine.offload", "broker.unstack", "broker.fulfil"
+    }
+    order = ["broker.stack", "engine.offload", "broker.unstack",
+             "broker.fulfil"]
+    starts = [kids[n].start_us for n in order]
+    assert starts == sorted(starts)
+    engine_kids = {s.name for s in _children(spans, kids["engine.offload"])}
+    assert {"engine.launch", "engine.device_wait"} <= engine_kids
+    waits = [s for s in spans if s.name == "broker.queue_wait"]
+    assert sorted(group.args["requests"]) == sorted(
+        w.args["request"] for w in waits
+    ) == ["t0#0", "t1#0", "t2#0"]
+    assert all(s.tid == group.tid for s in kids.values())
+
+
+def test_broker_thread_waits_are_spans_on_the_dispatch_thread():
+    """Under the running flush thread: idle (nothing queued) and
+    flush_wait (the deadline, with the queue depth) are spans of the
+    dispatch thread, beside its dispatch_group."""
+    with obs_tracing.tracing() as tracer:
+        broker = DescriptorBroker(OffloadEngine(), flush_interval_s=0.02)
+        desc = broker.make_descriptor(
+            "SCAN", p=P, payload_bytes=N * 4, op="sum"
+        )
+        client = broker.client("t0")
+        broker.start()
+        try:
+            time.sleep(0.2)  # the thread waits, idle, for a request
+            tickets = [client.submit(desc.encode(), _x(i)) for i in range(2)]
+            for t in tickets:
+                t.result(30)
+        finally:
+            broker.stop()
+    spans = tracer.spans()
+    groups = [s for s in spans if s.name == "broker.dispatch_group"]
+    assert groups
+    thread = {g.tid for g in groups}
+    assert len(thread) == 1
+    flush = [s for s in spans if s.name == "broker.flush_wait"]
+    idle = [s for s in spans if s.name == "broker.idle"]
+    assert flush and idle
+    assert {s.tid for s in flush + idle} == thread
+    assert all(s.args["queued"] >= 1 for s in flush)
+    assert all(s.parent_id is None for s in flush + idle)
+    served = [r for g in groups for r in g.args["requests"]]
+    waits = [s.args["request"] for s in spans if s.name == "broker.queue_wait"]
+    assert sorted(served) == sorted(waits) == ["t0#0", "t0#1"]
+
+
+def test_tracer_spans_are_profiler_host_events(tmp_path):
+    """A collecting tracer's context span is also a host event, by name, in
+    a jax.profiler trace; a retroactive add_span is not."""
+    import glob
+    import gzip
+    import json
+
+    import jax
+
+    tracer = obs_tracing.Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracer.span("test.annotated", "host"):
+            jnp.ones(4).block_until_ready()
+        t = obs_tracing.now_us()
+        tracer.add_span("test.retroactive", "host", t - 10.0, t)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.trace.json.gz"),
+                        recursive=True)
+    with gzip.open(path, "rb") as f:
+        events = json.loads(f.read())["traceEvents"]
+    names = {e.get("name") for e in events if e.get("ph") == "X"}
+    assert "test.annotated" in names
+    assert "test.retroactive" not in names
+    assert [s.name for s in tracer.spans()] == [
+        "test.annotated", "test.retroactive"
+    ]
+
+
+# ------------------------------------------------------------ round count
+
+
+def _eager_rounds(coll, p, algo, x):
+    """Round spans and TracingBackend.rounds of the schedule run eagerly."""
+    from repro.core import algorithms as alg
+    from repro.core.operators import get_operator
+    from repro.core.reduce_ops import (
+        allreduce_schedule, barrier_schedule, reduce_schedule,
+    )
+    from repro.core.scan_collective import sim_scan
+
+    op = get_operator("sum")
+    run = {
+        "scan": lambda b: sim_scan(x, op, p, algorithm=algo, backend=b),
+        "exscan": lambda b: sim_scan(
+            x, op, p, algorithm=algo, inclusive=False, backend=b
+        ),
+        "reduce": lambda b: reduce_schedule(b, x, op, algorithm=algo),
+        "allreduce": lambda b: allreduce_schedule(b, x, op, algorithm=algo),
+        "barrier": lambda b: barrier_schedule(b, algorithm=algo),
+    }[coll]
+    with obs_tracing.tracing() as tracer:
+        backend = obs_tracing.TracingBackend(alg.SimBackend(p), tracer)
+        run(backend)
+    spans = [s for s in tracer.spans() if s.cat == "round"]
+    return backend.rounds, len(spans)
+
+
+@pytest.mark.parametrize("p", [2, 8])
+@pytest.mark.parametrize(
+    "coll", ["scan", "exscan", "reduce", "allreduce", "barrier"]
+)
+def test_compiled_rounds_equal_the_eager_traced_count(coll, p):
+    from repro.core.packet import WireDType
+
+    eng = OffloadEngine()
+    desc = eng.make_descriptor(
+        coll, p=p, payload_bytes=N * 4, op="sum", data_type=WireDType.INT32
+    )
+    x = jnp.arange(p * N, dtype=jnp.int32).reshape(p, N)
+    eng.offload(desc, x)
+    (sched,) = eng._cache.values()
+    counted, round_spans = _eager_rounds(coll, p, desc.algo_type, x)
+    assert sched.rounds == counted == round_spans > 0
+    if coll == "scan" and p == 8:
+        assert sched.rounds == 3
+
+
+def test_planned_rounds_are_the_sum_over_phases():
+    """A planned schedule's round count is the sum of its phases' rounds,
+    as the traced interpreter's phase spans report them."""
+    eng, desc, x, _, spans = _traced_scan_spans()
+    (offload,) = [s for s in spans if s.name == "engine.offload"]
+    phase_rounds = sum(s.args.get("rounds", 0) for s in spans
+                       if s.cat == "phase")
+    assert offload.args["rounds"] == phase_rounds > 0
+    eng.offload(desc, x)  # the jitted schedule: counted when it compiles
+    assert {s.rounds for s in eng._cache.values()} == {phase_rounds}
+
+
+def test_rounds_dispatched_sums_the_offload_spans_rounds():
+    """EngineTelemetry.rounds_dispatched over a window equals the sum of
+    the rounds args on that window's engine.offload spans, which also
+    carry the schedule's algo and the bytes per rank."""
+    from repro.core.packet import WireDType
+
+    eng = OffloadEngine()
+    descs = [
+        eng.make_descriptor(c, p=P, payload_bytes=N * 4, op="sum",
+                            data_type=WireDType.INT32)
+        for c in ("scan", "exscan", "allreduce")
+    ]
+    x = jnp.ones((P, N), jnp.int32)
+    eng.offload(descs[0], x)  # before the window
+    before = eng.telemetry.snapshot()["rounds_dispatched"]
+    with obs_tracing.tracing() as tracer:
+        for d in descs + descs[:1]:
+            eng.offload(d, x)
+    after = eng.telemetry.snapshot()["rounds_dispatched"]
+    offloads = [s for s in tracer.spans() if s.name == "engine.offload"]
+    assert len(offloads) == 4
+    assert after - before == sum(s.args["rounds"] for s in offloads) > 0
+    assert all(s.args["bytes_per_rank"] == N * 4 for s in offloads)
+    assert offloads[0].args["algo"] == eng._cache[
+        eng._cache_key(descs[0], None)
+    ].algo
 
 
 # ------------------------------------------------------------ CI module
