@@ -54,14 +54,6 @@ Perm = List[Tuple[int, int]]
 #: other algorithms chunk at whole-schedule granularity (chunk-major).
 DOUBLING_ALGORITHMS = frozenset({"hillis_steele", "invertible_doubling"})
 
-#: per-leaf byte ceiling for the contiguous-shift permute fast path. The
-#: padded-copy realization moves the *whole* block (p rows) where the
-#: dynamic-update-slice chain moves only the p-d shifted rows in place, so
-#: pad wins while the per-op dispatch constant dominates (small blocks) and
-#: loses once the copy is bandwidth-bound (big blocks) — measured crossover
-#: on the sim backend sits near 64 KiB.
-SHIFT_FAST_PATH_MAX_BYTES = 65536
-
 
 # ---------------------------------------------------------------------------
 # Backends
@@ -165,13 +157,17 @@ class SimBackend(Backend):
     used by property tests and by the host-orchestrated baseline, where each
     ``permute`` models one host-driven message hop.
 
-    Contiguous shifts (every doubling round, every structural EXSCAN shift)
-    take a streaming fast path: one padded block copy instead of a chain of
-    per-pair dynamic-update-slices — the software analogue of the NIC
-    DMA-ing one contiguous segment. Values are identical either way (same
-    permutation, same zero fill); the fast path is gated to small blocks
-    (:data:`SHIFT_FAST_PATH_MAX_BYTES`) where the per-op constant, not the
-    copy bandwidth, dominates.
+    Every permute is one data-independent expression over the whole stacked
+    leaf, so it fuses with the combine that reads it. A contiguous shift
+    (every doubling round, every structural EXSCAN shift) is one ``lax.pad``
+    along the rank axis with ``(d, -d)`` padding: zero rows enter on one
+    side and the shifted-out rows drop on the other — the software analogue
+    of the NIC DMA-ing one contiguous segment. Any other permutation (the
+    butterfly, the broadcast from the last rank, tree and multicast hops) is
+    one stack of static rows, a zero row where no pair names the
+    destination. Neither realization updates single rows in place: on a
+    chip the rank axis lies inside the stack's tiles, so a per-row update
+    rewrites the whole stack.
     """
 
     def __init__(self, p: int):
@@ -181,23 +177,24 @@ class SimBackend(Backend):
         return jnp.arange(self.p, dtype=jnp.int32)
 
     def permute(self, tree: PyTree, perm: Perm) -> PyTree:
-        d = as_contiguous_shift(list(perm), self.p)
+        perm = list(perm)
+        d = as_contiguous_shift(perm, self.p)
+        if d is not None:
 
-        def shuffle(a):
-            if (
-                d is not None
-                and a.size * a.dtype.itemsize <= SHIFT_FAST_PATH_MAX_BYTES
-            ):
-                tail = [(0, 0)] * (a.ndim - 1)
-                if d > 0:
-                    return jnp.pad(a[: self.p - d], [(d, 0)] + tail)
-                return jnp.pad(a[-d:], [(0, -d)] + tail)
-            out = jnp.zeros_like(a)
-            for src, dst in perm:
-                out = out.at[dst].set(a[src])
-            return out
+            def shift(a):
+                pads = [(d, -d, 0)] + [(0, 0, 0)] * (a.ndim - 1)
+                return lax.pad(a, jnp.zeros((), a.dtype), pads)
 
-        return jax.tree.map(shuffle, tree)
+            return jax.tree.map(shift, tree)
+        src_of = {dst: src for src, dst in perm}  # the last pair wins
+
+        def gather(a):
+            zero = jnp.zeros(a.shape[1:], a.dtype)
+            return jnp.stack(
+                [a[src_of[r]] if r in src_of else zero for r in range(len(a))]
+            )
+
+        return jax.tree.map(gather, tree)
 
 
 # ---------------------------------------------------------------------------

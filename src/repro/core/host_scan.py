@@ -73,20 +73,16 @@ class _HostSteppedBackend(alg.SimBackend):
     """Each permute is its own dispatch + host sync (the un-offloaded path)."""
 
     def permute(self, tree, perm):
-        out = _jit_shuffle(tuple(perm), tree)
+        out = _jit_shuffle(self.p, tuple(perm), tree)
         jax.tree.map(lambda a: a.block_until_ready(), out)
         return out
 
 
-@partial(jax.jit, static_argnums=0)
-def _jit_shuffle(perm: Tuple[Tuple[int, int], ...], tree: PyTree) -> PyTree:
-    def shuffle(a):
-        out = jnp.zeros_like(a)
-        for src, dst in perm:
-            out = out.at[dst].set(a[src])
-        return out
-
-    return jax.tree.map(shuffle, tree)
+@partial(jax.jit, static_argnums=(0, 1))
+def _jit_shuffle(
+    p: int, perm: Tuple[Tuple[int, int], ...], tree: PyTree
+) -> PyTree:
+    return alg.SimBackend(p).permute(tree, list(perm))
 
 
 def time_host_scan(

@@ -18,6 +18,7 @@ from repro.core import (
     SSD,
     SUM,
     CollectiveDescriptor,
+    SimBackend,
     algorithm_step_count,
     cost_table,
     estimate_cost,
@@ -25,6 +26,7 @@ from repro.core import (
     host_scan,
     schedule_trace,
     select_algorithm,
+    sim_allreduce,
     sim_scan,
 )
 
@@ -160,3 +162,98 @@ def test_descriptor_roundtrip_and_node_type():
     assert CollectiveDescriptor(comm_size=8, rank=7).node_type.name == "ROOT"
     assert CollectiveDescriptor(comm_size=8, rank=0).node_type.name == "LEAF"
     assert CollectiveDescriptor(comm_size=8, rank=3).node_type.name == "INTERNAL"
+
+
+# ------------------------------------------- SimBackend.permute realization
+
+
+def _permute_cases(p):
+    shifts = {
+        f"shift{d:+d}": [(i, i + d) for i in range(max(0, -d), min(p, p - d))]
+        for d in (1, -1, 2, -2, 4, -4)
+    }
+    cases = dict(shifts)
+    if p & (p - 1) == 0:
+        cases["xor"] = [(j, j ^ 2) for j in range(p)]
+    cases["multicast"] = [(p - 1, j) for j in range(p - 1)]
+    cases["single"] = [(1, p - 2)]
+    cases["duplicate_dst"] = [(0, 3), (2, 3), (1, 0), (4, 3)]
+    cases["empty"] = []
+    return cases
+
+
+def _leaves(shape, kind, rng):
+    ints = rng.integers(-(2**31), 2**31, size=shape, dtype=np.int64)
+    if kind == "int32":
+        return ints.astype(np.int32)
+    if kind == "float32":
+        return rng.normal(size=shape).astype(np.float32)
+    flags = (rng.random(shape[:1]) > 0.3).astype(np.float32)
+    return (ints.astype(np.int32), flags)
+
+
+@pytest.mark.parametrize("width", [4, 32768])  # stack under / over 64 KiB
+@pytest.mark.parametrize("kind", ["int32", "float32", "pytree"])
+@pytest.mark.parametrize("p", [8, 5])
+def test_sim_permute_matches_per_pair_reference(p, kind, width):
+    """Every permutation SimBackend realizes equals zeros with each
+    ``out[dst] = a[src]`` applied in list order, bit for bit."""
+    rng = np.random.default_rng(p * 100003 + width)
+    x = _leaves((p, width), kind, rng)
+    backend = SimBackend(p)
+    for name, perm in _permute_cases(p).items():
+        got = backend.permute(jax.tree.map(jnp.asarray, x), perm)
+
+        def want_leaf(a):
+            out = np.zeros_like(a)
+            for src, dst in perm:
+                out[dst] = a[src]
+            return out
+
+        want = jax.tree.map(want_leaf, x)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert g.shape == w.shape and g.dtype == w.dtype, name
+            assert np.asarray(g).tobytes() == w.tobytes(), (name, perm)
+
+
+def _numpy_collective(coll, op, x):
+    scan = (
+        np.cumsum(x, axis=0, dtype=x.dtype) if op == "sum"
+        else np.maximum.accumulate(x, axis=0)
+    )
+    if coll == "SCAN":
+        return scan
+    if coll == "EXSCAN":
+        first = np.zeros_like(x[:1]) if op == "sum" else np.full_like(
+            x[:1], np.finfo(x.dtype).min
+        )
+        return np.concatenate([first, scan[:-1]])
+    return np.broadcast_to(scan[-1:], x.shape)
+
+
+def _run_sim(coll, op, x, p, algo):
+    if coll == "ALLREDUCE":
+        return sim_allreduce(x, op, p, algorithm=algo)
+    return sim_scan(x, op, p, algorithm=algo, inclusive=coll == "SCAN")
+
+
+@pytest.mark.parametrize("width", [4, 32768])
+@pytest.mark.parametrize("p", [8, 5])
+@pytest.mark.parametrize("coll", ["SCAN", "EXSCAN", "ALLREDUCE"])
+def test_sim_collectives_bitwise_vs_numpy(coll, p, width):
+    """SCAN, EXSCAN and ALLREDUCE on the stacked sim path, for every
+    schedule ``select_algorithm`` may return: int32 sum (wrapping) and
+    float32 max equal plain numpy bit for bit."""
+    rng = np.random.default_rng(p * 7919 + width)
+    cases = {
+        "sum": (rng.integers(-(2**31), 2**31, size=(p, width), dtype=np.int64)
+                .astype(np.int32), SUM),
+        "max": (rng.normal(size=(p, width)).astype(np.float32), MAX),
+    }
+    for name, (x, op) in cases.items():
+        want = _numpy_collective(coll, name, x)
+        for algo in ALGOS:
+            if algo == "invertible_doubling" and op.inverse is None:
+                continue  # never selected for max
+            got = np.asarray(_run_sim(coll, name, jnp.asarray(x), p, algo))
+            assert got.tobytes() == want.tobytes(), (name, algo)

@@ -6,8 +6,9 @@ what Pallas interpret mode cannot — tiles Mosaic refuses, scoped VMEM
 overruns — at real sizes: the scan kernels at Mamba2-130M's shapes, the
 prefix scan with its custom-VJP backward pass, the fused collective kernel
 in both forms up to the largest payload the VMEM budget admits (the spmd
-form compiles although ``supports_plan`` refuses it on a chip), and the
-op-per-round ``lower_spmd`` schedules.
+form compiles although ``supports_plan`` refuses it on a chip), the
+op-per-round ``lower_spmd`` schedules, and the engine's stacked (sim-mode)
+schedules at 64 MiB per rank.
 
 The topology is described in a module fixture and never at import: one
 process at a time may load the TPU library, and every xdist worker imports
@@ -18,6 +19,7 @@ read back without a chip).
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +29,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.operators import get_operator
+from repro.core.packet import CollType
 from repro.kernels import ops, pallas_collective
+from repro.offload.engine import OffloadEngine
 from repro.offload.planner import (
     PhaseKind,
     PlanPhase,
@@ -257,3 +261,27 @@ def test_lower_spmd_compiles(mesh, coll):
         (4, MIB // 4), jnp.float32, sharding=NamedSharding(mesh, P("i"))
     )
     assert "collective-permute" in _hlo(_spmd(fn, mesh), x)
+
+
+def _entry_ops(text: str) -> list:
+    """Opcodes of the compiled module's entry computation."""
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[: entry.index("\n}")]
+    return re.findall(r"=\s*\S+\s+([a-z][\w-]*)\(", entry)
+
+
+@pytest.mark.parametrize("coll", ["SCAN", "EXSCAN", "ALLREDUCE"])
+def test_sim_schedule_has_no_row_updates(one_chip, coll):
+    """The stacked schedule at 64 MiB per rank, p=8: every permute is one
+    fused pass over the (8, 2**24) stack, never a chain of per-rank
+    dynamic-update-slices (the rank axis lies inside the stack's tiles, so
+    each would rewrite all 512 MiB), and a SCAN's shifts copy no slice."""
+    fn = OffloadEngine._build_sim(
+        CollType[coll], get_operator("sum"), "hillis_steele", 8, 0
+    )
+    x = jax.ShapeDtypeStruct((8, 2**24), jnp.int32, sharding=one_chip)
+    ops_ = _entry_ops(_hlo(fn, x))
+    assert "fusion" in ops_, ops_
+    assert "dynamic-update-slice" not in ops_, ops_
+    if coll == "SCAN":
+        assert "slice" not in ops_, ops_
