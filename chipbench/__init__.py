@@ -9,5 +9,8 @@ traffic and payload generators (``traffic``), the plain references
 reader per per-layer metric (``metrics/<name>.py``). Configurations
 (``configs/<name>.json``) and traffic mixes (``workloads/<cell>.json``) are
 data files found by name; the program under test is driven only through its
-public entry points (``systems``).
+public entry points, by the system module the configuration names
+(``systems/<system>.py``), and a training configuration names its
+yardstick (``<reference>.py``) the same way (``spec`` says what each
+exports).
 """

@@ -22,6 +22,10 @@ follows the program, because it checks the program's arithmetic:
 float8 rounding with a per-tensor scale (e4m3, and e5m2 for cotangents):
 the control, the reference computed in the next precision below the
 configuration's bfloat16.
+
+This is the yardstick that ``configs/mamba2_130m_train.json`` names
+(``"reference": "mamba_ref"``); ``systems/trainer.py`` reads everything
+model-specific from it by the names ``spec`` lists.
 """
 
 from __future__ import annotations
@@ -33,9 +37,28 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from chipbench import peaks
+
 Params = Dict[str, Any]
 HIGHEST = lax.Precision.HIGHEST
 F32 = jnp.float32
+
+#: where the program's model config (the key's field) must agree with the
+#: configuration's ``model`` (the value's key)
+PROGRAM_FIELDS = {
+    "num_layers": "n_layer", "d_model": "d_model", "vocab_size": "vocab_size",
+    "padded_vocab": "padded_vocab_size", "ssm_state": "d_state",
+    "ssm_head_dim": "headdim", "ssm_expand": "expand",
+    "ssm_chunk": "chunk_size", "tie_embeddings": "tie_embeddings",
+    "dtype": "dtype",
+}
+
+#: limits of the numbers compared, each between the sound program's
+#: readings and the control's or a fault's (PERF.md gives them). Read and
+#: not compared: step 1's and step 3's loss gaps and the worst leaf's
+#: gradient gap (PERF.md says why).
+LIMITS = {"loss_gap_step2": 0.025, "grad_norm_gap_median": 0.0022,
+          "change_norm_gap": 0.4}
 
 #: leaf names under ``blocks/mamba`` in the order keys are drawn
 MIXER_LEAVES = (
@@ -131,6 +154,9 @@ def fp8(x):
 
 fp8.defvjp(lambda x: (fp8(x), None),
            lambda _, g: (_round8(g, jnp.float8_e5m2, 57344.0),))
+
+#: the control's rounding of every contraction's operands
+control = fp8
 
 
 def _ein(q: Optional[Callable]):
@@ -322,3 +348,9 @@ def gaps(got: Tuple, want: Tuple, exclude_below: float = 1e-3) -> Dict[str, floa
                grad_norm_gap_median=float(np.median(grad_gaps)),
                change_norm_gap=change_gap)
     return out
+
+
+def flops_per_token(m: Dict[str, Any]) -> float:
+    """Forward + backward FLOPs one token requires
+    (``peaks.mamba2_flops_per_token``)."""
+    return peaks.mamba2_flops_per_token(m)

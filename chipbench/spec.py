@@ -5,6 +5,22 @@ A cell of ``BENCHMARK.json`` names a workload file
 ``configs/<config>.json``; each per-layer metric is read by
 ``metrics/<metric>.py``. Adding a cell, a configuration or a metric is
 adding files: nothing here lists them.
+
+The configuration names the rest, and each is a file too:
+
+* ``"system"``: the driver of the program under test,
+  ``systems/<system>.py``, which exports ``Cell`` (see ``systems`` for
+  what a cell does);
+* ``"reference"`` (training configurations): the model's yardstick,
+  ``<reference>.py`` beside this file, which imports nothing of the
+  program and exports ``PROGRAM_FIELDS``, ``LIMITS``, ``param_shapes``,
+  ``init_params``, ``Reference``, ``gaps``, ``control`` and
+  ``flops_per_token`` (``mamba_ref`` is one).
+
+Both are modules of this package, imported by name
+(``chipbench.systems.<system>``, ``chipbench.<reference>``): a new system
+or yardstick is a new file whose name the configuration gives; no file
+that is there changes.
 """
 
 from __future__ import annotations
@@ -12,6 +28,7 @@ from __future__ import annotations
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 #: the checkout's root (holds BENCHMARK.json) and the benchmark's directory
@@ -41,6 +58,11 @@ def load_reader(metric: str, base: Path = BENCH_DIR) -> Callable[[Any], Optional
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module.read
+
+
+def load_yardstick(name: str) -> ModuleType:
+    """The plain reference ``chipbench.<name>`` a configuration names."""
+    return importlib.import_module(f"chipbench.{name}")
 
 
 def cell(bench: Dict[str, Any], name: str) -> Dict[str, Any]:

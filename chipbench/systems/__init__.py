@@ -1,9 +1,22 @@
 """Drivers of the program under test, one per kind of system a
-configuration names (``"system"`` in ``configs/<name>.json``)."""
+configuration names (``"system"`` in ``configs/<name>.json``).
+
+A new system is one new file, ``systems/<system>.py``, that exports
+``Cell``: a class built as ``Cell(config, workload, *, seed, seconds,
+devices, scratch, tracing)`` whose instance the harness drives in this
+order: ``setup()`` (everything before the window: build, inputs, warm-up),
+``counters()`` (before and after the window: ``{group: {name: number}}``),
+``window() -> Window``, ``spans()`` (the program's spans that started in
+the window, or None), ``release()`` (the program's state freed, its
+answers on the host) and ``check(control=False) -> List[Check]``. Nothing
+here lists the systems: ``build`` imports ``chipbench.systems.<system>`` by
+the configuration's name for it.
+"""
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 from typing import Any, Dict, List, Optional
 
 
@@ -43,16 +56,28 @@ class Check:
 
 
 def build(config: Dict[str, Any], workload: Dict[str, Any], **kw):
-    kind = config["system"]
-    if kind == "offload":
-        from chipbench.systems.offload import OffloadCell
+    """The ``Cell`` of ``chipbench.systems.<config["system"]>``, built."""
+    name = f"{__name__}.{config['system']}"
+    try:
+        module = importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        raise ValueError(f"unknown system {config['system']!r} in configuration "
+                         f"{config['name']!r}") from None
+    return module.Cell(config, workload, **kw)
 
-        return OffloadCell(config, workload, **kw)
-    if kind == "trainer":
-        from chipbench.systems.trainer import TrainerCell
 
-        return TrainerCell(config, workload, **kw)
-    raise ValueError(f"unknown system {kind!r} in configuration {config['name']!r}")
+def window_spans(window: Window) -> Optional[list]:
+    """The spans of the program's tracer that started inside ``window``;
+    None where no collecting tracer is installed."""
+    from repro.obs import tracing
+
+    tracer = tracing.get_tracer()
+    if not tracer.enabled:
+        return None
+    lo, hi = window.start * 1e6, window.end * 1e6
+    return [s for s in tracer.spans() if lo <= s.start_us <= hi]
 
 
 def jax_key(seed: int):

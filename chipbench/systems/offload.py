@@ -5,9 +5,15 @@ through ``ServiceClient.submit`` of ``build_offload_service()``; each
 request is timed from its due time to its result on the tenant's side.
 
 ``entry: "engine"`` (closed loop): one caller issues
-``OffloadEngine.offload`` back to back on inputs already on the device
-(sim mode: the ranks stacked on one chip), each timed from the call to
-``block_until_ready`` on its output.
+``OffloadEngine.offload`` back to back on inputs already on the device,
+each timed from the call to ``block_until_ready`` on its output.
+
+The configuration's ``mode`` says where the ranks are. ``"sim"``: stacked
+on one chip. ``"driver"`` (engine entry only): one rank on each chip of a
+flat mesh over the cell's chips (``launch.mesh.auto_mesh``); the engine
+runs its own ``jit(shard_map(...))`` (``offload(..., axis_name, mesh)``),
+and each input is placed before the window with its rank's row on that
+rank's chip.
 
 Every answer of the open loop, and a reservoir sample drawn from the seed
 of the closed loop's, is compared bit for bit with ``reference.collective``
@@ -25,7 +31,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from chipbench import reference, traffic
-from chipbench.systems import Check, Window, jax_key
+from chipbench.systems import Check, Window, jax_key, window_spans
 
 #: each request's result is awaited this long past the window's close
 LATE_S = 60.0
@@ -66,11 +72,24 @@ class OffloadCell:
 
     def setup(self) -> None:
         import jax
+        from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
         mode = self.config["mode"]
-        if mode != "sim":
+        if mode == "sim":
+            self.place = SingleDeviceSharding(self.devices[0])
+            self.offload_kw: Dict[str, Any] = {}
+        elif mode == "driver":
+            from repro.launch.mesh import auto_mesh
+
+            if self.entry != "engine" or len(self.devices) != self.p:
+                raise ValueError("driver mode runs the engine entry with one "
+                                 f"rank on each chip: {self.p} ranks, "
+                                 f"{len(self.devices)} chips, entry {self.entry!r}")
+            mesh = auto_mesh((self.p,), ("ranks",), devices=self.devices)
+            self.place = NamedSharding(mesh, PartitionSpec("ranks"))
+            self.offload_kw = {"axis_name": "ranks", "mesh": mesh}
+        else:
             raise ValueError(f"unknown engine mode {mode!r}")
-        self.place = self.devices[0]
         self._jax = jax
         if self.entry == "service":
             self._setup_service()
@@ -215,14 +234,13 @@ class OffloadCell:
         self._mark("payloads")
         for _ in range(2):
             for vi, d in enumerate(self.descs):
-                jax.block_until_ready(eng.offload(d, self.inputs[vi][0]))
+                jax.block_until_ready(eng.offload(d, self.inputs[vi][0], **self.offload_kw))
         self._mark("warm_up")
 
     def _device_inputs(self, vi: int, v: traffic.Shape, k_in: int) -> List[Any]:
         """Large payloads are made on the device from the seed."""
         import jax
         import jax.numpy as jnp
-        from jax.sharding import SingleDeviceSharding
 
         shape = (self.p, v.count)
 
@@ -232,7 +250,7 @@ class OffloadCell:
                 return jax.lax.bitcast_convert_type(bits, jnp.int32)
             return jax.random.normal(key, shape, jnp.float32)
 
-        fn = jax.jit(make, out_shardings=SingleDeviceSharding(self.place))
+        fn = jax.jit(make, out_shardings=self.place)
         base = jax.random.fold_in(jax_key(self.seed), vi)
         return [fn(jax.random.fold_in(base, k)) for k in range(k_in)]
 
@@ -323,7 +341,7 @@ class OffloadCell:
             vi = i % nv
             ki = (i // nv) % k_in
             ts = time.perf_counter()
-            out = eng.offload(self.descs[vi], self.inputs[vi][ki])
+            out = eng.offload(self.descs[vi], self.inputs[vi][ki], **self.offload_kw)
             jax.block_until_ready(out)
             te = time.perf_counter()
             lat.append(te - ts)
@@ -347,14 +365,7 @@ class OffloadCell:
         return win
 
     def spans(self) -> Optional[list]:
-        """The program's own spans that started inside the window."""
-        from repro.obs import tracing
-
-        tracer = tracing.get_tracer()
-        if not tracer.enabled:
-            return None
-        lo, hi = self.window_.start * 1e6, self.window_.end * 1e6
-        return [s for s in tracer.spans() if lo <= s.start_us <= hi]
+        return window_spans(self.window_)
 
     # ------------------------------------------------------------- check
 
@@ -418,3 +429,6 @@ class OffloadCell:
 def _third_p50(lat: np.ndarray, due: np.ndarray, seconds: float, third: int) -> Optional[float]:
     sel = (due >= third * seconds / 3) & (due < (third + 1) * seconds / 3) & np.isfinite(lat)
     return float(np.median(lat[sel]) * 1e6) if sel.any() else None
+
+
+Cell = OffloadCell

@@ -1,13 +1,18 @@
 """The training step, driven as a training job drives it.
 
 Set-up builds the program's ``Trainer`` (``repro.launch.train
-.build_trainer``), gives it weights made on the device from the seed by
-``mamba_ref.init_params`` and the yardstick's packed-document feed, and
-drives its step function through the first steps: those compile, and their
-losses, step 1's gradient (from the optimizer's first moment) and the
-weights' change over them are what the check compares with the plain
-reference. The window then runs the same step function on the same state,
-one step in flight, and counts the tokens of every step it completed.
+.build_trainer``), gives it weights made on the device from the seed by the
+yardstick's ``init_params`` and the packed-document feed, and drives its
+step function through the first steps: those compile, and their losses,
+step 1's gradient (from the optimizer's first moment) and the weights'
+change over them are what the check compares with the plain reference. The
+window then runs the same step function on the same state, one step in
+flight, and counts the tokens of every step it completed.
+
+Everything model-specific comes from the yardstick the configuration names
+(``"reference"``: ``chipbench/<reference>.py``, see ``spec``): which fields
+of the program's config must match, the weights' layout and draw, the plain
+reference, the gaps read, the control and the limits.
 """
 
 from __future__ import annotations
@@ -18,24 +23,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from chipbench import mamba_ref, traffic
-from chipbench.systems import Check, Window, jax_key
-
-#: where the program's config must agree with the yardstick's
-_PROGRAM_FIELDS = {
-    "num_layers": "n_layer", "d_model": "d_model", "vocab_size": "vocab_size",
-    "padded_vocab": "padded_vocab_size", "ssm_state": "d_state",
-    "ssm_head_dim": "headdim", "ssm_expand": "expand",
-    "ssm_chunk": "chunk_size", "tie_embeddings": "tie_embeddings",
-    "dtype": "dtype",
-}
-
-#: limits of the numbers compared, each between the sound program's
-#: readings and the control's or a fault's (PERF.md gives them). Read and
-#: not compared: step 1's and step 3's loss gaps and the worst leaf's
-#: gradient gap (PERF.md says why).
-LIMITS = {"loss_gap_step2": 0.025, "grad_norm_gap_median": 0.0022,
-          "change_norm_gap": 0.4}
+from chipbench import spec, traffic
+from chipbench.systems import Check, Window, jax_key, window_spans
 
 
 class TrainerCell:
@@ -47,9 +36,11 @@ class TrainerCell:
         self.seed, self.seconds = int(seed), float(seconds)
         self.devices = list(devices)
         self.scratch = Path(scratch)
+        self.tracing = bool(tracing)
+        self.ref = spec.load_yardstick(config["reference"])
         self.model, self.opt_cfg = config["model"], config["optimizer"]
         self.batch, self.seq = int(workload["batch"]), int(workload["seq_len"])
-        self.limits = dict(LIMITS, **config.get("limits", {}))
+        self.limits = dict(self.ref.LIMITS, **config.get("limits", {}))
 
     def _feed(self):
         m = self.model
@@ -65,9 +56,12 @@ class TrainerCell:
         from jax.sharding import NamedSharding, PartitionSpec
 
         from repro.launch import train
+        from repro.obs import tracing
         from repro.optim.adamw import init_opt_state
         from repro.sharding.specs import use_topology
 
+        if self.tracing and not tracing.get_tracer().enabled:
+            tracing.install_tracer()
         o = self.opt_cfg
         argv = [
             "--arch", self.config["arch"], "--mesh", "local",
@@ -87,7 +81,7 @@ class TrainerCell:
             is_leaf=lambda x: isinstance(x, PartitionSpec))
         pshard, oshard = shard(tr.specs[0]), shard(tr.specs[1])
         key = jax_key(self.seed)
-        init = jax.jit(lambda k: mamba_ref.init_params(k, self.model), out_shardings=pshard)
+        init = jax.jit(lambda k: self.ref.init_params(k, self.model), out_shardings=pshard)
         params = init(key)
         opt = jax.jit(init_opt_state, out_shardings=oshard)(params)
 
@@ -96,7 +90,7 @@ class TrainerCell:
             lambda a: jax.numpy.linalg.norm(a.ravel()) / (1 - b1), t))
         change = jax.jit(lambda master, k: jax.tree.map(
             lambda a, b: jax.numpy.linalg.norm((a - b.astype(a.dtype)).ravel()),
-            master, mamba_ref.init_params(k, self.model)))
+            master, self.ref.init_params(k, self.model)))
         losses, gnorms = [], []
         for step in range(int(self.workload["setup_steps"])):
             params, opt, metrics = self._step(params, opt)
@@ -116,8 +110,9 @@ class TrainerCell:
 
     def _check_program(self, tr) -> None:
         cfg = tr.api.cfg
-        got = {k: getattr(cfg, k) for k in _PROGRAM_FIELDS}
-        want = {k: self.model[v] for k, v in _PROGRAM_FIELDS.items()}
+        fields = self.ref.PROGRAM_FIELDS
+        got = {k: getattr(cfg, k) for k in fields}
+        want = {k: self.model[v] for k, v in fields.items()}
         if got != want:
             raise ValueError(f"the program's model config {got} is not the "
                              f"configuration's {want}")
@@ -129,7 +124,7 @@ class TrainerCell:
                                  f"is not the configuration's {self.opt_cfg[k]}")
         import jax
 
-        want_shapes = mamba_ref.param_shapes(self.model)
+        want_shapes = self.ref.param_shapes(self.model)
         got_shapes = tr.api.param_shapes()
         if jax.tree.structure(got_shapes) != jax.tree.structure(want_shapes) or any(
             (a.shape, a.dtype) != (b.shape, b.dtype)
@@ -145,10 +140,11 @@ class TrainerCell:
     # ------------------------------------------------------------- window
 
     def counters(self) -> Dict[str, Any]:
+        """The trainer keeps no counters of its own."""
         return {}
 
     def spans(self) -> Optional[list]:
-        return None
+        return window_spans(self.window_)
 
     def window(self) -> Window:
         import jax
@@ -192,9 +188,9 @@ class TrainerCell:
         reference from the same weights and batches, and the reference."""
         import jax
 
-        ref = mamba_ref.Reference(self.model, self.opt_cfg,
-                                  int(self.workload["reference_rows_per_block"]), q)
-        params = jax.jit(lambda k: mamba_ref.init_params(k, self.model))(jax_key(self.seed))
+        ref = self.ref.Reference(self.model, self.opt_cfg,
+                                 int(self.workload["reference_rows_per_block"]), q)
+        params = jax.jit(lambda k: self.ref.init_params(k, self.model))(jax_key(self.seed))
         return ref.run(params, self._reference_batches(), rows), ref
 
     def _reference_batches(self):
@@ -202,16 +198,17 @@ class TrainerCell:
 
     def check(self, control: bool = False) -> List[Check]:
         """The program's first steps against the float32 reference. With
-        ``control`` the reference in float8 stands in the program's place,
-        and the readings of two faults planted in the reference are kept
-        beside it: half of the batch left out, and the state left unchanged."""
+        ``control`` the reference with the yardstick's ``control`` rounding
+        stands in the program's place, and the readings of two faults
+        planted in the reference are kept beside it: half of the batch left
+        out, and the state left unchanged."""
         want, ref = self._reference()
         if control:
-            got, low = self._reference(q=mamba_ref.fp8)
+            got, low = self._reference(q=self.ref.control)
             got_gnorms = low.global_grad_norms
         else:
             got, got_gnorms = self.setup_readings, self.global_grad_norms
-        readings = mamba_ref.gaps(got, want)
+        readings = self.ref.gaps(got, want)
         info = self.window_.info
         info.update(
             readings=readings, reference_losses=want[0], program_losses=got[0],
@@ -220,7 +217,8 @@ class TrainerCell:
                             "change": [got[2][k], want[2][k]]} for k in want[1]},
         )
         if control:
-            info["control"] = "float8 reference in the program's place"
+            info["control"] = (f"the reference with {self.ref.control.__name__} "
+                               "contractions in the program's place")
             info["fault_half_batch"] = self._fault_readings(
                 self._reference(rows=self.batch // 2)[0], want)
             info["fault_state_unchanged"] = self._fault_readings(
@@ -232,15 +230,14 @@ class TrainerCell:
         taken at the first weights, no first moment, no change."""
         import jax
 
-        params = jax.jit(lambda k: mamba_ref.init_params(k, self.model))(jax_key(self.seed))
-        params = jax.tree.map(lambda a: a.astype(mamba_ref.F32), params)
+        params = jax.jit(lambda k: self.ref.init_params(k, self.model))(jax_key(self.seed))
+        params = jax.tree.map(lambda a: a.astype(jax.numpy.float32), params)
         losses = [float(ref.loss_and_grads(params, b)[0]) for b in self._reference_batches()]
         zeros = {k: 0.0 for k in want[1]}
         return losses, zeros, dict(zeros)
 
-    @staticmethod
-    def _fault_readings(got, want) -> Dict[str, Any]:
-        out = dict(mamba_ref.gaps(got, want), losses=got[0])
+    def _fault_readings(self, got, want) -> Dict[str, Any]:
+        out = dict(self.ref.gaps(got, want), losses=got[0])
         out["leaf_grad_norms"] = got[1]
         return out
 
@@ -250,3 +247,6 @@ def _by_path(tree) -> Dict[str, float]:
 
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
     return {jax.tree_util.keystr(p): float(v) for p, v in flat}
+
+
+Cell = TrainerCell

@@ -10,7 +10,8 @@ the same comparison and result line as the program's runs.
 * the training cell: a step that returns its state unchanged, and half of
   the batch left out with the mean taken over the rest.
 
-The sound program passes the same comparison.
+The sound program passes the same comparison, and a traced trainer passes
+on the program's spans from inside the window.
 """
 
 import dataclasses
@@ -25,18 +26,24 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import jax  # noqa: E402
 
-from chipbench import harness, peaks, spec  # noqa: E402
+from chipbench import harness, peaks, spec, systems  # noqa: E402
 
 SEED = 2**33 + 11
+
+
+def _files(cell_name, workload=None, config=None):
+    """The cell's workload and configuration, with some values replaced."""
+    wl = dict(spec.load_workload(cell_name), **(workload or {}))
+    cfg = spec.load_config(wl["config"])
+    for k, v in (config or {}).items():
+        cfg[k] = dict(cfg.get(k, {}), **v) if isinstance(v, dict) else v
+    return wl, cfg
 
 
 def _run(cell_name, *, workload=None, config=None, control=False, seconds=1.0):
     bench = spec.load_benchmark()
     cell = spec.cell(bench, cell_name)
-    wl = dict(spec.load_workload(cell_name), **(workload or {}))
-    cfg = spec.load_config(wl["config"])
-    for k, v in (config or {}).items():
-        cfg[k] = dict(cfg.get(k, {}), **v) if isinstance(v, dict) else v
+    wl, cfg = _files(cell_name, workload, config)
     return harness.run_cell(
         bench, cell, seed=SEED, seconds=seconds, trace=False,
         devices=jax.devices()[:1], t_start=time.perf_counter(), workload=wl,
@@ -143,3 +150,40 @@ def test_train_faults_are_not_correct(fault, small_mamba, monkeypatch):
     monkeypatch.setattr(train_loop, "build_train_step", fault(train_loop.build_train_step))
     out = _run("train.mamba2_130m", **TRAIN_SMALL)
     assert not out["correct"], out["checks"]
+
+
+def _spanned(build):
+    """A step that records a span of its own, as a program's model code may."""
+    def wrapped(api, *a, **k):
+        from repro.obs import tracing
+
+        step, shapes, specs = build(api, *a, **k)
+
+        def traced(params, opt, batch):
+            with tracing.get_tracer().span("model.step", "model"):
+                return step(params, opt, batch)
+        return traced, shapes, specs
+    return wrapped
+
+
+def test_traced_trainer_passes_on_the_programs_spans_in_the_window(small_mamba, monkeypatch):
+    from repro.obs import tracing
+    from repro.runtime import train_loop
+
+    monkeypatch.setattr(train_loop, "build_train_step", _spanned(train_loop.build_train_step))
+    wl, cfg = _files("train.mamba2_130m", **TRAIN_SMALL)
+    prev = tracing.set_tracer(None)  # the cell installs a fresh tracer
+    try:
+        cell = systems.build(cfg, wl, seed=SEED, seconds=0.5, devices=jax.devices()[:1],
+                             scratch=harness.SCRATCH, tracing=True)
+        cell.setup()
+        win = cell.window()
+        spans = cell.spans()
+        every = tracing.get_tracer().spans()
+    finally:
+        tracing.set_tracer(prev)
+    lo, hi = win.start * 1e6, win.end * 1e6
+    # the set-up's steps recorded spans too; only the window's are passed on
+    assert len(every) == len(spans) + wl["setup_steps"]
+    assert [s.name for s in spans] == ["model.step"] * win.attempted
+    assert all(lo <= s.start_us <= hi for s in spans)

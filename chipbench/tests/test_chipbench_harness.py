@@ -7,6 +7,7 @@ import os
 import re
 import subprocess
 import sys
+import time
 from pathlib import Path
 
 import numpy as np
@@ -64,16 +65,98 @@ def test_every_part_is_found_by_name(bench):
         assert (ROOT / c["file"]).is_file()
 
 
-def test_new_files_are_picked_up_without_edits(tmp_path):
-    (tmp_path / "configs").mkdir()
-    (tmp_path / "workloads").mkdir()
-    (tmp_path / "metrics").mkdir()
-    (tmp_path / "configs" / "cfg_x.json").write_text(json.dumps({"name": "cfg_x"}))
-    (tmp_path / "workloads" / "cell.x.json").write_text(json.dumps({"config": "cfg_x"}))
+#: a system of its own: a window of ``calls`` calls of 1 ms each, counted,
+#: judged by the limit its yardstick gives
+TOY_SYSTEM = """
+import time
+
+from chipbench import spec
+from chipbench.systems import Check, Window
+
+
+class Cell:
+    def __init__(self, config, workload, *, seed, seconds, devices, scratch,
+                 tracing=False):
+        self.ref = spec.load_yardstick(config["reference"])
+        self.n = int(workload["calls"])
+        self.calls = 0
+
+    def setup(self):
+        pass
+
+    def counters(self):
+        return {"toy": {"calls": self.calls}}
+
+    def window(self):
+        t0 = time.perf_counter()
+        self.calls += self.n
+        return Window(start=t0, end=t0 + 1.0, latencies_s=[1e-3] * self.n,
+                      attempted=self.n)
+
+    def spans(self):
+        return None
+
+    def release(self):
+        pass
+
+    def check(self, control=False):
+        return [Check("wrong_answers", int(control), self.ref.LIMITS["wrong_answers"])]
+"""
+
+TOY_REF = """
+LIMITS = {"wrong_answers": 0}
+
+
+def flops_per_token(model):
+    return 6.0 * model["n"]
+"""
+
+
+@pytest.fixture
+def toy(tmp_path, monkeypatch):
+    """A system ``toy``, a yardstick ``toy_ref``, a configuration, a cell and
+    two readers, as new files in a directory of their own that the package
+    ``chipbench`` searches besides its own."""
+    import importlib
+
+    import chipbench
+    from chipbench import systems
+
+    for d in ("configs", "workloads", "metrics", "systems"):
+        (tmp_path / d).mkdir()
+    (tmp_path / "configs" / "cfg_x.json").write_text(json.dumps(
+        {"name": "cfg_x", "system": "toy", "reference": "toy_ref", "model": {"n": 2}}))
+    (tmp_path / "workloads" / "cell.x.json").write_text(
+        json.dumps({"config": "cfg_x", "calls": 5}))
     (tmp_path / "metrics" / "metric.x.py").write_text("def read(run):\n    return 42.0\n")
-    wl = spec.load_workload("cell.x", tmp_path)
-    assert spec.load_config(wl["config"], tmp_path) == {"name": "cfg_x"}
-    assert spec.load_reader("metric.x", tmp_path)(None) == 42.0
+    (tmp_path / "metrics" / "toy_calls.py").write_text(
+        "def read(run):\n    return run.counter_delta('toy', 'calls')\n")
+    (tmp_path / "metrics" / "toy_flops.py").write_text(
+        "from chipbench import spec\n\n\ndef read(run):\n"
+        "    ref = spec.load_yardstick(run.config['reference'])\n"
+        "    return ref.flops_per_token(run.config['model'])\n")
+    (tmp_path / "systems" / "toy.py").write_text(TOY_SYSTEM)
+    (tmp_path / "toy_ref.py").write_text(TOY_REF)
+    monkeypatch.setattr(chipbench, "__path__", [*chipbench.__path__, str(tmp_path)])
+    monkeypatch.setattr(systems, "__path__", [*systems.__path__, str(tmp_path / "systems")])
+    importlib.invalidate_caches()
+    yield tmp_path
+    for name in ("chipbench.systems.toy", "chipbench.toy_ref"):
+        sys.modules.pop(name, None)
+
+
+def test_new_files_are_picked_up_without_edits(toy):
+    from chipbench import systems
+
+    wl = spec.load_workload("cell.x", toy)
+    cfg = spec.load_config(wl["config"], toy)
+    assert cfg["name"] == "cfg_x"
+    assert spec.load_reader("metric.x", toy)(None) == 42.0
+    # a system and a yardstick are found by the names the configuration gives
+    cell = systems.build(cfg, wl, seed=1, seconds=1.0, devices=[], scratch=toy)
+    assert cell.n == 5 and type(cell).__module__ == "chipbench.systems.toy"
+    ref = spec.load_yardstick(cfg["reference"])
+    assert ref is cell.ref and ref.flops_per_token({"n": 2}) == 12.0
     bench = {
         "end_to_end": [{"name": "setup_s"}, {"name": "a", "workloads": ["other"]}],
         "per_layer": [{"name": "metric.x", "moves": "setup_s"},
@@ -83,6 +166,62 @@ def test_new_files_are_picked_up_without_edits(tmp_path):
     e2e, layer = spec.cell_metrics(bench, "cell.x")
     assert [m["name"] for m in e2e] == ["setup_s"]
     assert [m["name"] for m in layer] == ["metric.x", "z"]
+
+
+def test_an_unknown_system_names_its_configuration(tmp_path):
+    from chipbench import systems
+
+    cfg = {"name": "cfg_y", "system": "no_such_system"}
+    with pytest.raises(ValueError, match="cfg_y"):
+        systems.build(cfg, {}, seed=1, seconds=1.0, devices=[], scratch=tmp_path)
+    with pytest.raises(ModuleNotFoundError):
+        spec.load_yardstick("no_such_ref")
+
+
+def test_a_new_system_runs_through_the_harness_as_files_alone(toy, capsys):
+    import jax
+
+    from chipbench import harness
+
+    bench = {
+        "workloads": [{"name": "cell.x", "config": "cfg_x", "traffic": "toy", "chips": 1}],
+        "end_to_end": [{"name": "latency_p50_us", "unit": "us"},
+                       {"name": "setup_s", "unit": "s"}],
+        "per_layer": [{"name": "toy_calls", "unit": "count", "moves": "latency_p50_us"},
+                      {"name": "toy_flops", "unit": "FLOP", "moves": "latency_p50_us"}],
+    }
+    kw = dict(seed=3, seconds=1.0, devices=jax.devices()[:1], base=toy,
+              peaks_of=lambda kind: peaks.PEAKS["TPU v5 lite"])
+    out = harness.run_cell(bench, bench["workloads"][0], trace=False,
+                           t_start=time.perf_counter(), **kw)
+    assert out["correct"] and out["attempted"] == 5
+    assert out["metrics"]["latency_p50_us"]["value"] == pytest.approx(1000.0)
+    assert set(out["metrics"]) == {"latency_p50_us", "setup_s"}
+    traced = harness.run_cell(bench, bench["workloads"][0], trace=True,
+                              t_start=time.perf_counter(), **kw)
+    assert traced["metrics"] == {"toy_calls": {"value": 5, "unit": "count"},
+                                 "toy_flops": {"value": 12.0, "unit": "FLOP"}}
+    ctl = harness.run_cell(bench, bench["workloads"][0], trace=False, control=True,
+                           t_start=time.perf_counter(), **kw)
+    assert not ctl["correct"]
+    assert json.loads(capsys.readouterr().out.splitlines()[-1]) == ctl
+
+
+def test_yardstick_work_is_the_work_functions_and_train_mfu_reads_it():
+    from chipbench import harness, mamba_ref
+
+    cfg = spec.load_config("mamba2_130m_train")
+    m = cfg["model"]
+    assert spec.load_yardstick(cfg["reference"]).flops_per_token(m) == \
+        mamba_ref.flops_per_token(m) == peaks.mamba2_flops_per_token(m)
+    peak = peaks.PEAKS["TPU v5 lite"]
+    run = harness.Run(
+        cell={"chips": 1}, workload={}, config=cfg, peaks=peak, seconds=20.0,
+        setup_s=0.0, window=None, counters_before={}, counters_after={},
+        e2e={"train_tokens_per_s": 27047.0},
+    )
+    want = 100.0 * 27047.0 * peaks.mamba2_flops_per_token(m) / peak["bf16_flops_per_s"]
+    assert spec.load_reader("train_mfu")(run) == want
 
 
 def _trace(events):
